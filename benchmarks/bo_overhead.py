@@ -90,6 +90,8 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")

@@ -51,4 +51,6 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -69,4 +69,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -275,5 +275,7 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     import sys
     run(quick="--quick" in sys.argv)

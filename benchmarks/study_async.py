@@ -230,4 +230,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -50,4 +50,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.core.simulator import enable_compile_cache
+    enable_compile_cache()
     main()

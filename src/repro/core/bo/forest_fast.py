@@ -66,12 +66,8 @@ def _on_tpu() -> bool:
     # this runs on every suggestion round
     if not _HAS_JAX:
         return False
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    from ...kernels.ops import on_tpu
+    return on_tpu()
 
 
 def acquisition_backend() -> str:

@@ -56,6 +56,7 @@ forces a recompilation of an already-compiled engine.
 
 from __future__ import annotations
 
+import copy
 import logging
 from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
@@ -309,7 +310,10 @@ def _blocked_cumsum(x):
     nb = (n + pad) // blk
     tri = jnp.asarray(np.tril(np.ones((blk, blk), np.float32)))
     t = xp.reshape(B * nb, blk).astype(jnp.float32)
-    within = (t @ tri.T).astype(jnp.uint32)         # block-local inclusive
+    # HIGHEST: a TPU's default precision would round the packed counters
+    # to bf16's 8-bit mantissa
+    within = jnp.matmul(t, tri.T, precision=lax.Precision.HIGHEST
+                        ).astype(jnp.uint32)      # block-local inclusive
     within = within.reshape(B, nb, blk)
     totals = within[:, :, -1]
     offsets = jnp.cumsum(totals, axis=-1) - totals  # exclusive, (B, nb)
@@ -580,7 +584,7 @@ class _HeMemDef(_EngineDef):
 
     def observe(self, st, kv, keys, e, reads, writes, est_wall):
         sr, sw = self._draws(kv, keys, e, reads, writes)
-        samples = (sr + sw) @ jnp.ones(self.n, jnp.float32)
+        samples = jnp.sum(sr + sw, axis=-1)
         since = st["since"] + samples
         k = jnp.floor(since / kv["trigger"]).astype(jnp.int32)
         p = kv["cool_pages"]
@@ -659,7 +663,7 @@ class _MemtisDef(_EngineDef):
         sr, sw = monitor_draw2(keys, e, reads, writes, kv["sp"], kv["wsp"])
         rc = st["rc"] + sr
         wc = st["wc"] + sw
-        samples = (sr + sw) @ jnp.ones(self.n, jnp.float32)
+        samples = jnp.sum(sr + sw, axis=-1)
         cool_c = st["cool"] + est_wall
         cool = cool_c >= kv["cool_period"]
         cool_c = jnp.where(cool, 0.0, cool_c)
@@ -947,9 +951,8 @@ def _build_step(edef: "_EngineDef", const, page_bytes, scale,
         cum_mig = cum_mig + n_promote + n_demote
 
         acc_sum = acc.sum()
-        inf_f = in_fast.astype(jnp.float32)
-        reads_f = inf_f @ reads
-        writes_f = inf_f @ writes
+        reads_f = jnp.sum(jnp.where(in_fast, reads, 0.0), axis=-1)
+        writes_f = jnp.sum(jnp.where(in_fast, writes, 0.0), axis=-1)
         acc_f = reads_f + writes_f
         reads_s = reads.sum() - reads_f
         writes_s = writes.sum() - writes_f
@@ -958,7 +961,7 @@ def _build_step(edef: "_EngineDef", const, page_bytes, scale,
         else:
             pb = n_promote * np.float32(page_bytes)
             db = n_demote * np.float32(page_bytes)
-            w_mig = (pmask | dmask).astype(jnp.float32) @ writes
+            w_mig = jnp.sum(jnp.where(pmask | dmask, writes, 0.0), axis=-1)
         wall_ms, stall_s, sampling_s, hit = _access_cost(
             jnp, acc_f, acc_sum - acc_f, reads_s, writes_s, pb, db, w_mig,
             est_wall, samples, overhead_ms, const)
@@ -982,7 +985,11 @@ def init_carry(edef: "_EngineDef", kv, keys, est0):
     because every monitoring draw is keyed by the *absolute* epoch index
     carried in the ``xs`` epoch-id stream, not by scan position.
     """
-    B, n = edef.B, edef.n
+    B, n = len(keys), edef.n
+    if edef.B != B:
+        # the per-device def of a pmapped loop: the carry spans the batch
+        edef = copy.copy(edef)
+        edef.B = B
     return (jnp.zeros((B, n), dtype=bool), jnp.zeros(n, dtype=bool),
             jnp.asarray(est0, dtype=jnp.float32), edef.init(kv),
             jnp.zeros(B, dtype=jnp.float32), jnp.asarray(keys))
@@ -1063,12 +1070,9 @@ def reset_recompile_warnings() -> None:
 
 
 def _n_devices() -> int:
-    """Local XLA device count (1 unless the host is split, e.g. via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)."""
-    try:
-        return jax.local_device_count()
-    except Exception:  # pragma: no cover - no backend initialized
-        return 1
+    """Local XLA device count (on a CPU host 1 unless it is split, e.g.
+    via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)."""
+    return jax.local_device_count()
 
 
 def _get_compiled(engine_name, B, n, n_epochs, fast_cap, sampler, scale,

@@ -195,12 +195,6 @@ class CompiledServing:
             allocated[None, :], est, jnp.int32(self.H))
         return eng, pm[0], dm[0]
 
-    def _mig(self, dst, src, dst_rows, src_rows):
-        r = kops.page_migrate(dst.reshape(dst.shape[0], -1),
-                              src.reshape(src.shape[0], -1),
-                              dst_rows, src_rows)
-        return r.reshape(dst.shape)
-
     def _apply(self, st, pmask, dmask):
         """Apply one epoch's migration masks: batched demote (HBM->host),
         then batched promote into the freed slots — promote page-ids
@@ -214,8 +208,8 @@ class CompiledServing:
         d_valid = d_ids < n
         d_rows = jnp.where(d_valid, d_ids, n)                # host dump row
         d_slots = jnp.where(d_valid, slots[jnp.minimum(d_ids, n - 1)], H)
-        host_k = self._mig(st["host_k"], st["hbm_k"], d_rows, d_slots)
-        host_v = self._mig(st["host_v"], st["hbm_v"], d_rows, d_slots)
+        host_k = kops.page_migrate(st["host_k"], st["hbm_k"], d_rows, d_slots)
+        host_v = kops.page_migrate(st["host_v"], st["hbm_v"], d_rows, d_slots)
         slots = jnp.where(dm, -1, slots)
         posn = st["page_of_slot"][:H]
         owner = jnp.maximum(posn, 0)
@@ -228,8 +222,8 @@ class CompiledServing:
         valid = (p_ids < n) & (f_slots < H)
         p_rows = jnp.where(valid, p_ids, n)
         p_slots = jnp.where(valid, f_slots, H)
-        hbm_k = self._mig(st["hbm_k"], host_k, p_slots, p_rows)
-        hbm_v = self._mig(st["hbm_v"], host_v, p_slots, p_rows)
+        hbm_k = kops.page_migrate(st["hbm_k"], host_k, p_slots, p_rows)
+        hbm_v = kops.page_migrate(st["hbm_v"], host_v, p_slots, p_rows)
         slot_of = jnp.concatenate([slots, st["slot_of"][n:]])
         slot_of = slot_of.at[p_rows].set(jnp.where(valid, p_slots, -1))
         pos = jnp.concatenate([posn, st["page_of_slot"][H:]])

@@ -64,6 +64,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import sys
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
@@ -530,19 +531,66 @@ _POOL = None
 _POOL_SIZE = 0
 
 
-def compile_cache_dir() -> str:
-    """The XLA persistent-compilation-cache directory shipped to worker
-    shards (and honoured by the parent when it sets the env itself).
+#: the checkout this package runs from (``<checkout>/src/repro/core``)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
-    ``JAX_COMPILATION_CACHE_DIR`` overrides; the default is a stable
-    per-user path under the system temp dir so successive pools — and
-    successive *processes* — warm-start instead of re-jitting the epoch
-    loop per worker."""
-    import tempfile
+
+def compile_cache_dir() -> str:
+    """The XLA persistent-compilation-cache directory: the parent's (see
+    :func:`enable_compile_cache`) and the one shipped to worker shards.
+
+    ``JAX_COMPILATION_CACHE_DIR`` overrides; the default is the fixed
+    ``<checkout>/.jax_cache``, so successive pools and successive
+    processes in one checkout warm-start instead of re-jitting the epoch
+    loop.  The path is part of the cache key, so it holds no temp-dir, pid
+    or time component."""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        tempfile.gettempdir(), f"repro-xla-cache-{os.getuid()}")
+        _CHECKOUT, ".jax_cache")
     os.makedirs(d, exist_ok=True)
     return d
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process at
+    :func:`compile_cache_dir`; returns the directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other directory is set here.  Otherwise the variable is set to the
+    default, for a later import of jax and for child processes, and an
+    already-imported jax is pointed there too.  Call it at a program's
+    entry, before the first compilation.  It does not import jax."""
+    d = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = d
+        if "jax" in sys.modules:
+            sys.modules["jax"].config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+def jax_backend_is_tpu() -> bool:
+    """True when JAX's backend in this process is a TPU.  Where
+    ``JAX_PLATFORMS`` rules a TPU out, answers without importing jax."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    try:
+        import jax  # noqa: F401
+    except ImportError:  # pragma: no cover - env without jax
+        return False
+    from ..kernels.ops import on_tpu
+    return on_tpu()
+
+
+def refuse_children_on_tpu(what: str) -> None:
+    """Raise instead of starting child processes that would import JAX
+    while this process's backend is a TPU: a chip belongs to one process
+    at a time, so the children would fail or hang."""
+    if jax_backend_is_tpu():
+        raise RuntimeError(
+            f"{what} would start child processes that import JAX, but the "
+            "JAX backend here is a TPU, which one process holds at a time; "
+            "run it in this process (workers=1)")
 
 
 def _worker_init(cache_dir: str) -> None:
@@ -567,7 +615,6 @@ def _get_pool(workers: int):
         # forking a parent whose XLA runtime is already initialized is
         # unsupported (threads are not inherited) and can hang the workers;
         # fall back to spawn once jax has been imported
-        import sys
         use_fork = "fork" in mp.get_all_start_methods() and \
             "jax" not in sys.modules
         ctx = mp.get_context("fork" if use_fork else "spawn")
@@ -616,10 +663,19 @@ def _shard_worker(args):
                             exact_select=exact_select)
 
 
-def _resolve_workers(workers, batch: int) -> int:
-    if workers in ("auto", 0, None):
+def _resolve_workers(workers, batch: int, backend: str = "numpy") -> int:
+    """Worker-process count for a batch.  ``"auto"`` is one per core,
+    except for the jax backend on a TPU, where it is 1; an explicit count
+    above 1 there raises (:func:`refuse_children_on_tpu`)."""
+    auto = workers in ("auto", 0, None)
+    if auto:
         workers = os.cpu_count() or 1
-    return max(1, min(int(workers), batch))
+    workers = max(1, min(int(workers), batch))
+    if workers > 1 and backend == "jax":
+        if auto and jax_backend_is_tpu():
+            return 1
+        refuse_children_on_tpu(f"backend='jax' with workers={workers}")
+    return workers
 
 
 def run_simulation_cells(cells,
@@ -679,7 +735,7 @@ def run_simulation_cells(cells,
     total = sum(len(cfgs) for _, _, cfgs in cells)
     if total == 0:
         return [[] for _ in range(n_cells)]
-    workers = _resolve_workers(workers, total)
+    workers = _resolve_workers(workers, total, backend)
     if workers > 1 and backend == "jax":
         # results are identical either way; worker processes share the XLA
         # persistent compile cache (see _worker_init), so only the first

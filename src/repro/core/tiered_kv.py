@@ -388,14 +388,13 @@ class TieredKVCache:
 
     def _promote(self, pid: int, slot: int):
         # device-side copy via the page-migration kernel datapath
-        flat = jnp.asarray(self.host_k[pid].reshape(1, -1), self.spec.dtype)
+        ids = (jnp.asarray([slot]), jnp.asarray([0]))
         self.hbm_k = kops.page_migrate(
-            self.hbm_k.reshape(self.hbm_pages, -1), flat,
-            jnp.asarray([slot]), jnp.asarray([0])).reshape(self.hbm_k.shape)
-        flatv = jnp.asarray(self.host_v[pid].reshape(1, -1), self.spec.dtype)
+            self.hbm_k, jnp.asarray(self.host_k[pid:pid + 1], self.spec.dtype),
+            *ids)
         self.hbm_v = kops.page_migrate(
-            self.hbm_v.reshape(self.hbm_pages, -1), flatv,
-            jnp.asarray([slot]), jnp.asarray([0])).reshape(self.hbm_v.shape)
+            self.hbm_v, jnp.asarray(self.host_v[pid:pid + 1], self.spec.dtype),
+            *ids)
         self._slot_of[pid] = slot
         self._page_of_slot[slot] = pid
 

@@ -461,7 +461,9 @@ class FleetExecutor:
         self.max_respawns = int(max_respawns) if max_respawns is not None \
             else int(workers)
         self.backoff_s = float(backoff_s)
-        from ..simulator import compile_cache_dir
+        from ..simulator import compile_cache_dir, refuse_children_on_tpu
+        if pool == "process" or fleet_spec is None or not fleet_spec.external:
+            refuse_children_on_tpu(f"a {pool!r} fleet of local workers")
         if pool == "process":
             self._fleet = _ProcessFleet(self.slots, self.heartbeat_s,
                                         self.faults, compile_cache_dir())

@@ -91,7 +91,8 @@ class TrialExecutor:
         self.pool_kind = pool
         self.timeout_s = timeout_s  # default per-unit hang bound
         if pool == "process":
-            from ..simulator import _get_pool
+            from ..simulator import _get_pool, refuse_children_on_tpu
+            refuse_children_on_tpu("pool='process'")
             self._pool = _get_pool(self.slots)
             self._owns_pool = False
         else:

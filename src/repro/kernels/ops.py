@@ -23,11 +23,17 @@ from . import ref as R
 FORCE: Optional[str] = None
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU.  A backend that fails to
+    initialize raises here rather than reading as "no TPU": that would
+    silently run the kernels in interpret mode."""
+    return jax.default_backend() == "tpu"
+
+
+def interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode (every backend but
+    the TPU)."""
+    return not on_tpu()
 
 
 def _use_pallas() -> bool:
@@ -35,7 +41,7 @@ def _use_pallas() -> bool:
         return True
     if FORCE == "ref":
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -43,7 +49,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if _use_pallas():
         from .flash_attention import flash_attention as fa
         return fa(q, k, v, causal=causal, window=window,
-                  logit_softcap=logit_softcap, interpret=not _on_tpu())
+                  logit_softcap=logit_softcap, interpret=interpret())
     return R.flash_attention_ref(q, k, v, causal=causal, window=window,
                                  logit_softcap=logit_softcap)
 
@@ -53,7 +59,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
     if _use_pallas() and logit_softcap == 0.0:
         from .paged_attention import paged_attention as pa
         return pa(q, k_pages, v_pages, block_table, lengths,
-                  interpret=not _on_tpu())
+                  interpret=interpret())
     return R.paged_attention_ref(q, k_pages, v_pages, block_table, lengths,
                                  logit_softcap=logit_softcap)
 
@@ -80,7 +86,7 @@ def select_topk(p_mask, p_heat, d_mask, d_heat, n_promote, n_demote,
     if mode == "pallas":
         from .select_topk import select_topk as sk
         return sk(p_mask, p_heat, d_mask, d_heat, n_promote, n_demote,
-                  interpret=not _on_tpu())
+                  interpret=interpret())
     if mode == "ref":
         return R.select_topk_ref(p_mask, p_heat, d_mask, d_heat,
                                  n_promote, n_demote)
@@ -110,7 +116,7 @@ def page_migrate(dst_pool, src_pool, dst_ids, src_ids):
     if _use_pallas():
         from .page_migrate import page_migrate as pm
         return pm(dst_pool, src_pool, dst_ids, src_ids,
-                  interpret=not _on_tpu())
+                  interpret=interpret())
     return R.page_migrate_ref(dst_pool, src_pool, dst_ids, src_ids)
 
 

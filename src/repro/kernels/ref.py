@@ -124,16 +124,13 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, lengths,
 def page_migrate_ref(dst_pool, src_pool, dst_ids, src_ids):
     """Copy pages src_pool[src_ids] -> dst_pool[dst_ids]; -1 ids are no-ops.
 
-    pools: (P, page_elems) — returns updated dst_pool.
+    pools: (P, *page) — returns updated dst_pool.
     """
-    n = src_ids.shape[0]
     valid = (src_ids >= 0) & (dst_ids >= 0)
-    src = jnp.where(valid, src_ids, 0)
-    dst = jnp.where(valid, dst_ids, 0)
-    rows = src_pool[src]
-    current = dst_pool[dst]
-    rows = jnp.where(valid[:, None], rows, current)
-    return dst_pool.at[dst].set(rows)
+    rows = src_pool[jnp.where(valid, src_ids, 0)].astype(dst_pool.dtype)
+    # no-op lanes scatter out of bounds and are dropped
+    dst = jnp.where(valid, dst_ids, dst_pool.shape[0])
+    return dst_pool.at[dst].set(rows, mode="drop")
 
 
 # ---------------------------------------------------------------------------

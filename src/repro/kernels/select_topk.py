@@ -26,10 +26,13 @@ sample counts/rates, and the conformance suite (``tests/test_select_topk``)
 pins both this kernel and the pure-jnp fallback (:func:`repro.kernels.ref.
 select_topk_ref`) to the numpy stable-sort reference bit-for-bit.
 
-The kernel is grid-parallel over batch rows; each program streams one padded
-(1, n) row of packed keys through VMEM (u32 row + masks ≈ 1 MiB at the
-backend's 64k-page ceiling).  On CPU it runs in interpret mode (CI); on TPU
-the compare/reduce passes map onto VPU lanes.
+The kernel's grid runs over batch rows.  Each program holds one row of
+packed keys in VMEM, padded to a multiple of 8 x 128 and laid out as
+``(n_pad // 128, 128)`` so it fills whole (8, 128) vector tiles (keys +
+masks, double-buffered, 2 MiB at the backend's 64k-page ceiling); the
+batch axis of each block is squeezed, and the per-row selection counts
+are scalar-prefetched into SMEM.  On CPU it runs in interpret mode (CI); on
+TPU the compare/reduce passes map onto VPU lanes.
 """
 
 from __future__ import annotations
@@ -66,11 +69,20 @@ def pack_keys(p_mask, p_heat, d_mask, d_heat):
     return vp, vd
 
 
+#: lanes of a TPU vector register; each row's keys are laid out as
+#: ``(n_pad // LANES, LANES)`` so a row fills whole (8, 128) vreg tiles
+LANES = 128
+#: row-length granule: ``n_pad`` is a multiple of 8 x 128, so the
+#: second-minor block dimension is a multiple of the 8-sublane tile
+_ROW_GRANULE = 8 * LANES
+
+
 def _kernel(kp_ref, kd_ref, vp_ref, vd_ref, pm_ref, dm_ref):
-    vp = vp_ref[...]                       # (1, n_pad) uint32 keys
+    b = pl.program_id(0)
+    vp = vp_ref[...]                       # (rows, LANES) uint32 keys
     vd = vd_ref[...]
-    kp = kp_ref[0, 0]                      # per-row selection counts (f32)
-    kd = kd_ref[0, 0]
+    kp = kp_ref[b]                         # per-row selection counts (f32)
+    kd = kd_ref[b]
 
     def count_ge(v, t):
         # counts stay < 2**24, exact in f32
@@ -95,8 +107,10 @@ def _kernel(kp_ref, kd_ref, vp_ref, vd_ref, pm_ref, dm_ref):
     # phase 3: fill from the boundary tier in page-index order — a second
     # bitwise search over descending-index weights (weights are distinct,
     # so the take-th largest threshold selects exactly `take` pages)
-    n_pad = vp.shape[-1]
-    iv = np.uint32(n_pad) - lax.broadcasted_iota(jnp.uint32, vp.shape, 1)
+    rows = vp.shape[0]
+    idx = (lax.broadcasted_iota(jnp.int32, vp.shape, 0) * LANES
+           + lax.broadcasted_iota(jnp.int32, vp.shape, 1))
+    iv = lax.bitcast_convert_type(rows * LANES - idx, jnp.uint32)
     wp = jnp.where(bound_p, iv, np.uint32(0))
     wd = jnp.where(bound_d, iv, np.uint32(0))
     sp = jnp.uint32(0)
@@ -124,29 +138,29 @@ def select_topk(p_mask, p_heat, d_mask, d_heat, n_promote, n_demote, *,
     """
     B, n = p_mask.shape
     vp, vd = pack_keys(p_mask, p_heat, d_mask, d_heat)
-    n_pad = -(-n // 128) * 128
+    n_pad = -(-n // _ROW_GRANULE) * _ROW_GRANULE
     if n_pad != n:  # padding keys are 0 == non-candidate
         vp = jnp.pad(vp, ((0, 0), (0, n_pad - n)))
         vd = jnp.pad(vd, ((0, 0), (0, n_pad - n)))
-    kp = jnp.floor(n_promote.astype(jnp.float32)).reshape(B, 1)
-    kd = jnp.floor(n_demote.astype(jnp.float32)).reshape(B, 1)
+    rows = n_pad // LANES
+    vp = vp.reshape(B, rows, LANES)
+    vd = vd.reshape(B, rows, LANES)
+    kp = jnp.floor(n_promote.astype(jnp.float32)).reshape(B)
+    kd = jnp.floor(n_demote.astype(jnp.float32)).reshape(B)
+    row = pl.BlockSpec((None, rows, LANES), lambda b, kp, kd: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[row, row],
+        out_specs=[row, row],
+    )
     pm, dm = pl.pallas_call(
         _kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n_pad), lambda b: (b, 0)),
-            pl.BlockSpec((1, n_pad), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_pad), lambda b: (b, 0)),
-            pl.BlockSpec((1, n_pad), lambda b: (b, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((B, n_pad), jnp.int32)],
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, rows, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((B, rows, LANES), jnp.int32)],
         interpret=interpret,
+        name="select_topk",
     )(kp, kd, vp, vd)
-    return pm[:, :n] != 0, dm[:, :n] != 0
+    return (pm.reshape(B, n_pad)[:, :n] != 0,
+            dm.reshape(B, n_pad)[:, :n] != 0)

@@ -212,6 +212,96 @@ def test_worker_init_points_jax_at_cache(tmp_path, monkeypatch):
     assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
 
 
+def test_compile_cache_dir_defaults_to_checkout(monkeypatch):
+    """Without the variable the cache sits at a fixed path in the checkout
+    (no temp-dir, pid or time component: the path is part of the key)."""
+    import os
+    from repro.core import simulator
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert simulator.compile_cache_dir() == os.path.join(root, ".jax_cache")
+
+
+@pytest.fixture
+def restore_cache_config():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    cc.reset_cache()
+
+
+def test_enable_compile_cache_honours_env(tmp_path, monkeypatch,
+                                          restore_cache_config):
+    from repro.core import simulator
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_compilation_cache_dir
+    assert simulator.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was   # nothing set
+
+
+def test_enable_compile_cache_defaults_to_checkout(monkeypatch,
+                                                   restore_cache_config):
+    import os
+    from repro.core import simulator
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")  # unset, restored
+    d = simulator.enable_compile_cache()
+    assert d.endswith(os.sep + ".jax_cache")
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == d
+    assert jax.config.jax_compilation_cache_dir == d
+
+
+# -- one process per chip ----------------------------------------------------
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the backend query answer 'TPU' (no program option: the query
+    itself is steered)."""
+    from repro.core import simulator
+    monkeypatch.setattr(simulator, "jax_backend_is_tpu", lambda: True)
+
+
+def test_backend_query_reads_jax_platforms_first(monkeypatch):
+    from repro.core import simulator
+    from repro.kernels import ops
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert simulator.jax_backend_is_tpu() is False
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert simulator.jax_backend_is_tpu() is True
+
+
+def test_jax_workers_refused_on_tpu(on_tpu):
+    from repro.core import simulator
+    from repro.core.knobs import get_space
+    from repro.core.simulator import run_simulation_batch
+    _fresh_pool()
+    wl = make_workload("gups", "8GiB-hot", threads=8, scale=0.02, seed=3)
+    cfgs = [get_space("hemem").default_config()] * 2
+    with pytest.raises(RuntimeError, match="TPU"):
+        run_simulation_batch(wl, "hemem", cfgs, "pmem-large",
+                             backend="jax", workers=2)
+    assert simulator._POOL is None, "a worker pool was started"
+
+
+def test_auto_workers_resolve_to_one_on_tpu(on_tpu):
+    import os
+    from repro.core import simulator
+    assert simulator._resolve_workers("auto", 8, "jax") == 1
+    assert simulator._resolve_workers(1, 8, "jax") == 1
+    assert simulator._resolve_workers("auto", 8, "numpy") == \
+        min(os.cpu_count() or 1, 8)
+
+
+def test_process_pools_refused_on_tpu(on_tpu):
+    from repro.core.tune_service import FleetExecutor
+    from repro.core.tune_service.executor import TrialExecutor
+    with pytest.raises(RuntimeError, match="TPU"):
+        FleetExecutor(workers=2, pool="process")
+    with pytest.raises(RuntimeError, match="TPU"):
+        TrialExecutor(slots=2, pool="process")
+
+
 def _count_cache_files(d):
     import os
     return sum(len(fs) for _, _, fs in os.walk(d))
