@@ -1,4 +1,4 @@
-"""Run every paper-figure benchmark + the roofline harness.
+"""Run every paper-figure benchmark.
 
 Usage:
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig1,fig2,...]
@@ -29,7 +29,6 @@ MODULES = [
     "fig13_memtis",
     "bo_overhead",
     "serving_tiered_kv",
-    "roofline",
 ]
 
 

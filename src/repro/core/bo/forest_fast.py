@@ -41,6 +41,8 @@ import numpy as np
 
 import importlib.util
 
+from .. import spans
+
 # availability probe only — jax itself is imported lazily (inside the jax
 # acquisition path), so `import repro.core` stays jax-free for numpy users
 _HAS_JAX = importlib.util.find_spec("jax") is not None
@@ -488,35 +490,36 @@ def suggest_topq(forest: FlatForest, X: np.ndarray, best: float,
     backend = backend if backend in ("jax", "numpy") else acquisition_backend()
     if valid is None:
         valid = np.ones(X.shape[0], dtype=bool)
-    if backend == "jax":
-        # pad node and pool axes to coarse buckets so the jit cache stays
-        # warm while the forest grows round over round (pad nodes are
-        # unreachable leaves; pad pool rows are masked out of selection)
-        N = X.shape[0]
-        M = forest.feature.shape[1]
-        Mp = max(64, 1 << int(M - 1).bit_length())
-        Np = -(-N // 512) * 512
-        pad_nodes = ((0, 0), (0, Mp - M))
-        Xp = np.zeros((Np, X.shape[1]))
-        Xp[:N] = X
-        vp = np.zeros(Np, dtype=bool)
-        vp[:N] = valid
-        from ...kernels import ops
-        fn = _acquire_jax(forest.max_depth, ops.select_path())
-        ei, sel = fn(
-            np.pad(forest.feature, pad_nodes,
-                   constant_values=-1).astype(np.int32),
-            np.pad(forest.threshold, pad_nodes),
-            np.pad(forest.left, pad_nodes).astype(np.int32),
-            np.pad(forest.right, pad_nodes).astype(np.int32),
-            np.pad(forest.value, pad_nodes), Xp,
-            float(best), float(y_mean), float(y_std), vp, q)
-        ei32 = np.asarray(ei)[:N]
-        idx = np.flatnonzero(np.asarray(sel)[:N])
-        return ei32, _order_selected(ei32, idx)
-    preds = predict_forest(forest, X)
-    mean, std = _moments(preds, y_mean, y_std)
-    ei32 = expected_improvement(mean, std, best).astype(np.float32)
-    order = np.argsort(-ei32, kind="stable")
-    picked = order[valid[order]][:q]
-    return ei32, picked
+    with spans.span("repro.bo.acquire", pool=X.shape[0], q=q):
+        if backend == "jax":
+            # pad node and pool axes to coarse buckets so the jit cache stays
+            # warm while the forest grows round over round (pad nodes are
+            # unreachable leaves; pad pool rows are masked out of selection)
+            N = X.shape[0]
+            M = forest.feature.shape[1]
+            Mp = max(64, 1 << int(M - 1).bit_length())
+            Np = -(-N // 512) * 512
+            pad_nodes = ((0, 0), (0, Mp - M))
+            Xp = np.zeros((Np, X.shape[1]))
+            Xp[:N] = X
+            vp = np.zeros(Np, dtype=bool)
+            vp[:N] = valid
+            from ...kernels import ops
+            fn = _acquire_jax(forest.max_depth, ops.select_path())
+            ei, sel = fn(
+                np.pad(forest.feature, pad_nodes,
+                       constant_values=-1).astype(np.int32),
+                np.pad(forest.threshold, pad_nodes),
+                np.pad(forest.left, pad_nodes).astype(np.int32),
+                np.pad(forest.right, pad_nodes).astype(np.int32),
+                np.pad(forest.value, pad_nodes), Xp,
+                float(best), float(y_mean), float(y_std), vp, q)
+            ei32 = np.asarray(ei)[:N]
+            idx = np.flatnonzero(np.asarray(sel)[:N])
+            return ei32, _order_selected(ei32, idx)
+        preds = predict_forest(forest, X)
+        mean, std = _moments(preds, y_mean, y_std)
+        ei32 = expected_improvement(mean, std, best).astype(np.float32)
+        order = np.argsort(-ei32, kind="stable")
+        picked = order[valid[order]][:q]
+        return ei32, picked

@@ -40,11 +40,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from .. import spans
 from ..knobs import Config, KnobSpace
 from . import forest_fast
 from .rf import RandomForest, resolve_mode as rf_resolve_mode
@@ -130,8 +130,9 @@ class SMACOptimizer:
         self._surrogate: Optional[RandomForest] = None
         self._seed_queue: List[Config] = [space.validate(c) for c
                                           in (seed_configs or [])]
-        #: cumulative surrogate-fit wall clock (the tuner's per-round
-        #: fit/acquisition breakdown reads deltas of this)
+        #: cumulative surrogate-fit wall clock, summed from the
+        #: ``repro.bo.fit`` spans (the tuner's per-round fit/acquisition
+        #: breakdown reads deltas of this)
         self.fit_s = 0.0
 
     # -- bookkeeping ---------------------------------------------------------
@@ -187,15 +188,16 @@ class SMACOptimizer:
     # -- surrogate ------------------------------------------------------------
     def surrogate(self) -> RandomForest:
         if self._surrogate is None:
-            t0 = time.perf_counter()
-            X = np.stack([self.space.encode(o.config)
-                          for o in self.observations])
-            y = np.array([o.value for o in self.observations])
-            self._surrogate = RandomForest(
-                n_trees=self.n_trees,
-                seed=int(self.rng.integers(2 ** 31)),
-                mode=self.surrogate_mode).fit(X, y)
-            self.fit_s += time.perf_counter() - t0
+            with spans.span("repro.bo.fit",
+                            n_obs=len(self.observations)) as sp:
+                X = np.stack([self.space.encode(o.config)
+                              for o in self.observations])
+                y = np.array([o.value for o in self.observations])
+                self._surrogate = RandomForest(
+                    n_trees=self.n_trees,
+                    seed=int(self.rng.integers(2 ** 31)),
+                    mode=self.surrogate_mode).fit(X, y)
+            self.fit_s += sp.s
         return self._surrogate
 
     # -- suggestion -----------------------------------------------------------
@@ -212,16 +214,19 @@ class SMACOptimizer:
 
         model = self.surrogate()
         best_val = self.best.value
-        if self.acquisition == "legacy":
-            cands = self._candidate_pool(self.n_candidates)
-            X = np.stack([self.space.encode(c) for c in cands])
-            mean, std = self._predict_legacy(model, X)
-            ei = expected_improvement_ref(mean, std, best_val)
-            return cands[int(np.argmax(ei))]
-        X = self._candidate_pool_encoded(self.n_candidates)
-        _, sel = forest_fast.suggest_topq(
-            model.forest, X, best_val, model._y_mean, model._y_std, q=1)
-        return self.space.decode_batch(X[sel])[0]
+        with spans.span("repro.bo.pool") as sp:
+            if self.acquisition == "legacy":
+                cands = self._candidate_pool(self.n_candidates)
+                sp.count(n_candidates=len(cands))
+                X = np.stack([self.space.encode(c) for c in cands])
+                mean, std = self._predict_legacy(model, X)
+                ei = expected_improvement_ref(mean, std, best_val)
+                return cands[int(np.argmax(ei))]
+            X = self._candidate_pool_encoded(self.n_candidates)
+            sp.count(n_candidates=len(X))
+            _, sel = forest_fast.suggest_topq(
+                model.forest, X, best_val, model._y_mean, model._y_std, q=1)
+            return self.space.decode_batch(X[sel])[0]
 
     @staticmethod
     def _predict_legacy(model: RandomForest,
@@ -321,33 +326,36 @@ class SMACOptimizer:
             return out
         model = self.surrogate()
         best_val = self.best.value
-        if self.acquisition == "legacy":
-            cands = self._candidate_pool(max(self.n_candidates,
-                                             64 * n_model))
-            X = self.space.encode_batch(cands)
-            mean, std = self._predict_legacy(model, X)
-            ei = expected_improvement_ref(mean, std, best_val)
-            seen = set()
-            for i in np.argsort(-ei, kind="stable"):
-                key = tuple(sorted(cands[i].items()))
-                if key in seen:
-                    continue
-                seen.add(key)
-                out.append(cands[i])
-                if len(seen) == n_model:
-                    break
-        else:
-            X = self._candidate_pool_encoded(max(self.n_candidates,
+        with spans.span("repro.bo.pool") as sp:
+            if self.acquisition == "legacy":
+                cands = self._candidate_pool(max(self.n_candidates,
                                                  64 * n_model))
-            # canonical rows are config fixpoints, so deduplication is a
-            # first-occurrence mask in encoded space
-            _, first = np.unique(X, axis=0, return_index=True)
-            valid = np.zeros(len(X), dtype=bool)
-            valid[first] = True
-            _, sel = forest_fast.suggest_topq(
-                model.forest, X, best_val, model._y_mean, model._y_std,
-                valid=valid, q=n_model)
-            out.extend(self.space.decode_batch(X[sel]))
+                sp.count(n_candidates=len(cands))
+                X = self.space.encode_batch(cands)
+                mean, std = self._predict_legacy(model, X)
+                ei = expected_improvement_ref(mean, std, best_val)
+                seen = set()
+                for i in np.argsort(-ei, kind="stable"):
+                    key = tuple(sorted(cands[i].items()))
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    out.append(cands[i])
+                    if len(seen) == n_model:
+                        break
+            else:
+                X = self._candidate_pool_encoded(max(self.n_candidates,
+                                                     64 * n_model))
+                sp.count(n_candidates=len(X))
+                # canonical rows are config fixpoints, so deduplication is a
+                # first-occurrence mask in encoded space
+                _, first = np.unique(X, axis=0, return_index=True)
+                valid = np.zeros(len(X), dtype=bool)
+                valid[first] = True
+                _, sel = forest_fast.suggest_topq(
+                    model.forest, X, best_val, model._y_mean, model._y_std,
+                    valid=valid, q=n_model)
+                out.extend(self.space.decode_batch(X[sel]))
         while len(out) < q:  # pool exhausted by dedup: fall back to random
             out.append(self.space.sample(self.rng))
         return out

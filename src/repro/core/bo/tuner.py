@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
 
+from .. import spans
 from ..knobs import Config, KnobSpace, get_space
 from .smac import Observation, RandomSearch, SMACOptimizer
 
@@ -36,8 +37,9 @@ class TuningResult:
     wall_s: float
     #: per-round wall-clock breakdown: each entry has ``ask_s`` (suggestion,
     #: including the surrogate fit), ``fit_s`` (the surrogate-fit share of
-    #: ask), ``eval_s`` (objective evaluation), ``tell_s`` and ``q`` — the
-    #: receipts for the BO-overhead acceptance claim (BENCH_bo.json)
+    #: ask), ``eval_s`` (objective evaluation), ``tell_s`` and ``q``: the
+    #: seconds of the round's ``repro.bo.ask``, ``repro.bo.fit``,
+    #: ``repro.study.eval`` and ``repro.bo.tell`` spans
     round_times: List[Dict[str, float]] = dataclasses.field(
         default_factory=list)
 
@@ -122,56 +124,56 @@ class TuningSession:
 
     def run(self, verbose: bool = False) -> TuningResult:
         t0 = time.time()
-
-        def cb(i, cfg, val):
-            if verbose:
-                best = min(o.value for o in self.optimizer.observations)
-                print(f"  iter {i + 1:3d}/{self.budget}: f={val:9.2f}s "
-                      f"best={best:9.2f}s", flush=True)
-
-        def fit_s() -> float:
-            return float(getattr(self.optimizer, "fit_s", 0.0))
-
-        round_times: List[Dict[str, float]] = []
+        opt = self.optimizer
         if self.batch_size > 1:
-            default_value = float(
-                self.objective_batch([self.space.default_config()])[0])
-            done = 0
-            while done < self.budget:
-                q = min(self.batch_size, self.budget - done)
-                fit0, ta = fit_s(), time.perf_counter()
-                cfgs = self.optimizer.ask_batch(q)
-                te = time.perf_counter()
-                vals = [float(v) for v in self.objective_batch(cfgs)]
-                tt = time.perf_counter()
-                self.optimizer.tell_batch(cfgs, vals, crn=self.crn)
-                tend = time.perf_counter()
-                round_times.append({
-                    "ask_s": te - ta, "fit_s": fit_s() - fit0,
-                    "eval_s": tt - te, "tell_s": tend - tt, "q": float(q)})
-                for j, (cfg, val) in enumerate(zip(cfgs, vals)):
-                    cb(done + j, cfg, val)
-                done += q
+            ask = opt.ask_batch
+
+            def evaluate(cfgs):
+                return [float(v) for v in self.objective_batch(cfgs)]
+
+            def tell(cfgs, vals):
+                opt.tell_batch(cfgs, vals, crn=self.crn)
         else:
             # the sequential loop, identical to optimizer.minimize() but
             # with the per-round ask/eval/tell walls recorded
-            default_value = float(self.objective(self.space.default_config()))
-            for i in range(self.budget):
-                fit0, ta = fit_s(), time.perf_counter()
-                cfg = self.optimizer.ask()
-                te = time.perf_counter()
-                val = float(self.objective(cfg))
-                tt = time.perf_counter()
-                self.optimizer.tell(cfg, val)
-                tend = time.perf_counter()
-                round_times.append({
-                    "ask_s": te - ta, "fit_s": fit_s() - fit0,
-                    "eval_s": tt - te, "tell_s": tend - tt, "q": 1.0})
-                cb(i, cfg, val)
+            def ask(q):
+                return [opt.ask()]
+
+            def evaluate(cfgs):
+                return [float(self.objective(cfgs[0]))]
+
+            def tell(cfgs, vals):
+                opt.tell(cfgs[0], vals[0])
+
+        def fit_s() -> float:
+            return float(getattr(opt, "fit_s", 0.0))
+
+        with spans.span("repro.study.eval", round=-1, q=1):
+            default_value = evaluate([self.space.default_config()])[0]
+        round_times: List[Dict[str, float]] = []
+        done = 0
+        while done < self.budget:
+            rnd = len(round_times)
+            q = min(self.batch_size, self.budget - done)
+            fit0 = fit_s()
+            with spans.round_of(rnd):
+                with spans.span("repro.bo.ask", round=rnd, q=q) as a:
+                    cfgs = ask(q)
+                with spans.span("repro.study.eval", round=rnd, q=q) as e:
+                    vals = evaluate(cfgs)
+                with spans.span("repro.bo.tell", round=rnd, q=q) as t:
+                    tell(cfgs, vals)
+            round_times.append({"ask_s": a.s, "fit_s": fit_s() - fit0,
+                                "eval_s": e.s, "tell_s": t.s, "q": float(q)})
+            if verbose:
+                best = min(o.value for o in opt.observations)
+                for j, val in enumerate(vals):
+                    print(f"  iter {done + j + 1:3d}/{self.budget}: "
+                          f"f={val:9.2f}s best={best:9.2f}s", flush=True)
+            done += q
         return TuningResult(
             engine=self.engine, scenario=self.scenario_key,
-            budget=self.budget,
-            history=list(self.optimizer.observations),
+            budget=self.budget, history=list(opt.observations),
             default_value=default_value, wall_s=time.time() - t0,
             round_times=round_times)
 

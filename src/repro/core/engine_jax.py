@@ -62,6 +62,8 @@ from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
+from . import spans
+
 log = logging.getLogger(__name__)
 
 # jax is imported lazily on first use: merely importing this module (which
@@ -1167,6 +1169,13 @@ def compiled_cache_info() -> List[Tuple]:
     return list(_COMPILED)
 
 
+def _host_bytes(*trees) -> int:
+    """Bytes of the numpy arrays among the leaves of ``trees``: what handing
+    them to a jitted call copies to the device."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(trees)
+               if isinstance(x, (np.ndarray, np.generic)))
+
+
 def run_epochs(workload, engine_name: str,
                sim_configs: Sequence[Mapping[str, Any]],
                const: Mapping[str, float], fast_cap: int, page_bytes: int,
@@ -1221,9 +1230,10 @@ def run_epochs(workload, engine_name: str,
         raise ValueError("epoch_start > 0 requires the carry returned by "
                          "the previous segment (return_carry=True)")
     seg = stop - start
-    trace = [workload.epoch_access(e) for e in range(start, stop)]
-    reads_t = np.stack([r for r, _ in trace]).astype(np.float32)
-    writes_t = np.stack([w for _, w in trace]).astype(np.float32)
+    with spans.span("repro.sim.trace", epochs=seg, pages=n):
+        trace = [workload.epoch_access(e) for e in range(start, stop)]
+        reads_t = np.stack([r for r, _ in trace]).astype(np.float32)
+        writes_t = np.stack([w for _, w in trace]).astype(np.float32)
     epoch_ids = np.arange(start, stop, dtype=np.int32)
     const = {k: np.float32(v) for k, v in const.items()}
     scale = workload.scale
@@ -1254,25 +1264,32 @@ def run_epochs(workload, engine_name: str,
         stacked = tuple(jnp.stack([o[i] for o in outs])
                         for i in range(len(outs[0])))
     else:
-        edef, run = _get_compiled(engine_name, B, n, seg, fast_cap, sampler,
-                                  scale, page_bytes, record_placement,
-                                  select_mode)
-        kv = edef.knobs(sim_configs)
-        if carry is None:
-            keys = base_keys(seeds, batch_offset, crn)
-            est0 = np.full(B, workload.epoch_ms, dtype=np.float32)
-            carry = init_carry(edef, kv, keys, est0)
-        else:
-            carry = jax.tree_util.tree_map(jnp.asarray, carry)
-        carry, stacked = run(kv, reads_t, writes_t, const, carry, epoch_ids)
+        with spans.span("repro.sim.launch") as sp:
+            n_compiled = len(_COMPILED)
+            edef, run = _get_compiled(engine_name, B, n, seg, fast_cap,
+                                      sampler, scale, page_bytes,
+                                      record_placement, select_mode)
+            kv = edef.knobs(sim_configs)
+            sp.count(h2d_bytes=_host_bytes(kv, reads_t, writes_t, const,
+                                           carry, epoch_ids),
+                     cache_miss=int(len(_COMPILED) > n_compiled))
+            if carry is None:
+                keys = base_keys(seeds, batch_offset, crn)
+                est0 = np.full(B, workload.epoch_ms, dtype=np.float32)
+                carry = init_carry(edef, kv, keys, est0)
+            else:
+                carry = jax.tree_util.tree_map(jnp.asarray, carry)
+            carry, stacked = run(kv, reads_t, writes_t, const, carry,
+                                 epoch_ids)
 
     names = ["wall_ms", "cum_migrations", "hit_rate", "sampling_ms",
              "stall_ms"]
     if record_placement:
         names.append("in_fast")
-    out = {name: np.asarray(arr) for name, arr in zip(names, stacked)}
-    if return_carry:
-        out["carry"] = carry_to_host(carry)
+    with spans.span("repro.sim.fetch"):
+        out = {name: np.asarray(arr) for name, arr in zip(names, stacked)}
+        if return_carry:
+            out["carry"] = carry_to_host(carry)
     # hand the materialized trace back so heatmap binning in the caller
     # does not regenerate it (procedural workloads pay O(n) per epoch)
     out["trace_reads"] = reads_t

@@ -70,7 +70,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence
 import numpy as np
 
 from ._deprecation import warn_deprecated
-from . import engine_jax
+from . import engine_jax, spans
 from .engine import make_batch_engine
 from .knobs import get_space
 from .pages import BatchTierState, PAGE_BYTES, migration_rate_pages
@@ -316,16 +316,30 @@ def _run_batch_jax(workload: Workload, engine_name: str,
                    exact_select: bool = True) -> List[SimResult]:
     """The compiled fast path: one ``lax.scan`` over epochs per batch (see
     :mod:`repro.core.engine_jax` for the backend contract)."""
+    with spans.span("repro.sim.run", B=len(configs),
+                    round=spans.current_round()):
+        scale = workload.scale
+        fast_cap = _fast_capacity(workload, fast_slow_ratio,
+                                  fast_capacity_pages)
+        sim_cfgs = [scale_config(engine_name, c, scale) for c in configs]
+        const = _epoch_consts(workload, engine_name, machine, PAGE_BYTES)
+        out = engine_jax.run_epochs(
+            workload, engine_name, sim_cfgs, const, fast_cap, PAGE_BYTES,
+            seeds, sampler, crn=crn, batch_offset=batch_offset,
+            record_placement=record_heatmap, exact_select=exact_select)
+        with spans.span("repro.sim.results"):
+            return _batch_results(workload, engine_name, configs, machine,
+                                  out, record_heatmap, heat_bins)
+
+
+def _batch_results(workload: Workload, engine_name: str,
+                   configs: Sequence[Mapping[str, Any]], machine: Machine,
+                   out: Dict[str, np.ndarray], record_heatmap: bool,
+                   heat_bins: int) -> List[SimResult]:
+    """One ``SimResult`` per config from :func:`engine_jax.run_epochs`'
+    per-epoch arrays."""
     B = len(configs)
     n = workload.n_pages
-    scale = workload.scale
-    fast_cap = _fast_capacity(workload, fast_slow_ratio, fast_capacity_pages)
-    sim_cfgs = [scale_config(engine_name, c, scale) for c in configs]
-    const = _epoch_consts(workload, engine_name, machine, PAGE_BYTES)
-    out = engine_jax.run_epochs(
-        workload, engine_name, sim_cfgs, const, fast_cap, PAGE_BYTES,
-        seeds, sampler, crn=crn, batch_offset=batch_offset,
-        record_placement=record_heatmap, exact_select=exact_select)
     wall = np.asarray(out["wall_ms"], dtype=np.float64)
     cum_mig = np.asarray(out["cum_migrations"], dtype=np.float64)
     hit_rate = np.asarray(out["hit_rate"], dtype=np.float64)

@@ -41,6 +41,7 @@ import dataclasses
 from typing import (Any, Dict, List, Mapping, Optional, Sequence, Tuple,
                     Union)
 
+from . import spans
 from .bo.tuner import TuningResult, TuningSession
 from .knobs import Config, KnobSpace
 from .simulator import (Machine, SimResult, get_machine,
@@ -416,14 +417,17 @@ class Study:
                                     ) -> List[float]:
                     return [r.total_s for r in self.run(configs=configs)]
 
-        session = TuningSession(
-            self.spec.engine.name, objective, scenario_key=self.key,
-            space=space, optimizer=optimizer, budget=budget, seed=seed,
-            n_init=n_init, random_prob=random_prob, batch_size=batch_size,
-            objective_batch=objective_batch if batch_size > 1 else None,
-            crn=self.spec.options.crn, surrogate=surrogate,
-            acquisition=acquisition)
-        return session.run(verbose=verbose)
+        with spans.span("repro.study.tune", budget=budget,
+                        batch_size=batch_size):
+            session = TuningSession(
+                self.spec.engine.name, objective, scenario_key=self.key,
+                space=space, optimizer=optimizer, budget=budget, seed=seed,
+                n_init=n_init, random_prob=random_prob,
+                batch_size=batch_size,
+                objective_batch=objective_batch if batch_size > 1 else None,
+                crn=self.spec.options.crn, surrogate=surrogate,
+                acquisition=acquisition)
+            return session.run(verbose=verbose)
 
     # -- sweep -------------------------------------------------------------
     def sweep(self, grid: Optional[Mapping[str, Sequence[Any]]] = None, *,
