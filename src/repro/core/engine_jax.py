@@ -51,13 +51,16 @@ Performance notes (what made the compiled loop beat the numpy reference):
 Jitted epoch functions are cached per ``(engine, n_pages, sampler)`` (plus
 the remaining static shape parameters) so repeated ``Study.tune``
 iterations never retrace; a one-line warning is logged when a new shape
-forces a recompilation of an already-compiled engine.
+forces a recompilation of an already-compiled engine.  Each workload's
+epoch trace is built and copied to the device once (``_cached_trace``),
+so a launch ships only ``(B,)`` knob vectors and scalars.
 """
 
 from __future__ import annotations
 
 import copy
 import logging
+import threading
 from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
 import numpy as np
@@ -565,9 +568,7 @@ class _HeMemDef(_EngineDef):
                 / self.COOL_UNIT_PAGES, 1.0).astype(np.float32),
         )
         p = kv["cool_pages"]
-        # static per config: each page's cooling chunk and chunks per sweep
-        kv["cj"] = (np.arange(self.n, dtype=np.int32)[None, :]
-                    // p[:, None]).astype(np.int32)
+        # static per config: chunks per cooling sweep
         kv["M"] = ((self.n + p - 1) // p).astype(np.int32)
         return kv
 
@@ -599,9 +600,7 @@ class _HeMemDef(_EngineDef):
         # the reference's per-trigger cursor loop
         M = kv["M"]
         m0 = st["cursor"] // p
-        cj = kv["cj"]
-        halv = (k // M)[:, None] + (
-            ((cj - m0[:, None]) % M[:, None]) < (k % M)[:, None])
+        halv = (k // M)[:, None] + _sweep_extra(self.n, p, m0, k % M, M)
         decay = jnp.exp2(-halv.astype(jnp.float32))
         rc = st["rc"] * decay + sr * factor[:, None]
         wc = st["wc"] * decay + sw * factor[:, None]
@@ -637,6 +636,20 @@ class _HeMemDef(_EngineDef):
         pmask, dmask = self.select(cand_p, heat, cand_d, heat,
                                    n_p2 * gate, n_d2 * gate)
         return st, pmask, dmask, jnp.zeros(self.B, dtype=jnp.float32)
+
+
+def _sweep_extra(n, p, m0, r, M):
+    """Pages that a cooling sweep halves once more than the rest: the ``r``
+    chunks of ``p`` pages from chunk ``m0`` on, wrapping after chunk
+    ``M - 1`` (the last chunk may be partial).  For page ``j`` this is
+    ``(j // p - m0) % M < r``, taken as page ranges so that no per-page
+    integer division runs in the scan.  Needs ``0 <= m0, r < M``."""
+    j = jnp.arange(n, dtype=jnp.int32)[None, :]
+    end = m0 + r
+    wrap = end > M
+    lo = (m0 * p)[:, None]
+    hi = (jnp.where(wrap, end - M, end) * p)[:, None]
+    return jnp.where(wrap[:, None], (j >= lo) | (j < hi), (j >= lo) & (j < hi))
 
 
 class _MemtisDef(_EngineDef):
@@ -1169,6 +1182,48 @@ def compiled_cache_info() -> List[Tuple]:
     return list(_COMPILED)
 
 
+class _Trace:
+    """One workload's whole-run epoch trace ``[0, n_epochs)``: the float32
+    host arrays, read-only, and their device copies once a compiled run
+    asks for them.  It lives on the workload (``_trace``) and goes with it;
+    every ``epoch_access`` is a pure function of the epoch index."""
+
+    __slots__ = ("epoch_access", "reads", "writes", "reads_d", "writes_d")
+
+    def __init__(self, workload):
+        self.epoch_access = workload.epoch_access
+        trace = [workload.epoch_access(e) for e in range(workload.n_epochs)]
+        self.reads = np.stack([r for r, _ in trace]).astype(np.float32)
+        self.writes = np.stack([w for _, w in trace]).astype(np.float32)
+        self.reads.setflags(write=False)
+        self.writes.setflags(write=False)
+        self.reads_d = self.writes_d = None
+
+    def on_device(self, start: int, stop: int):
+        """The device copies of epochs ``[start, stop)``, sliced there."""
+        if stop - start == len(self.reads):
+            return self.reads_d, self.writes_d
+        return tuple(lax.dynamic_slice_in_dim(a, start, stop - start)
+                     for a in (self.reads_d, self.writes_d))
+
+
+_TRACE_LOCK = threading.Lock()
+
+
+def _cached_trace(workload, device: bool) -> Tuple[_Trace, bool]:
+    """The whole-run trace of ``workload`` (copied to the device once if
+    ``device``) and whether it was cached.  A workload whose
+    ``epoch_access`` was replaced misses."""
+    with _TRACE_LOCK:
+        tr = getattr(workload, "_trace", None)
+        hit = tr is not None and tr.epoch_access == workload.epoch_access
+        if not hit:
+            tr = workload._trace = _Trace(workload)
+        if device and tr.reads_d is None:
+            tr.reads_d, tr.writes_d = jax.device_put((tr.reads, tr.writes))
+        return tr, hit
+
+
 def _host_bytes(*trees) -> int:
     """Bytes of the numpy arrays among the leaves of ``trees``: what handing
     them to a jitted call copies to the device."""
@@ -1208,8 +1263,10 @@ def run_epochs(workload, engine_name: str,
 
     Output dict: ``wall_ms``/``cum_migrations``/``hit_rate``/
     ``sampling_ms``/``stall_ms`` as ``(n_epochs, B)`` float arrays (segment
-    epochs only), plus ``in_fast`` ``(n_epochs, B, n)`` when
-    ``record_placement`` and ``carry`` when ``return_carry``.
+    epochs only), ``trace_reads``/``trace_writes`` (the segment's float32
+    trace, read-only views of the workload's cached trace), plus
+    ``in_fast`` ``(n_epochs, B, n)`` when ``record_placement`` and
+    ``carry`` when ``return_carry``.
     """
     if not have_jax():  # pragma: no cover - env without jax
         raise RuntimeError("backend='jax' requires jax; install it or use "
@@ -1230,10 +1287,12 @@ def run_epochs(workload, engine_name: str,
         raise ValueError("epoch_start > 0 requires the carry returned by "
                          "the previous segment (return_carry=True)")
     seg = stop - start
-    with spans.span("repro.sim.trace", epochs=seg, pages=n):
-        trace = [workload.epoch_access(e) for e in range(start, stop)]
-        reads_t = np.stack([r for r, _ in trace]).astype(np.float32)
-        writes_t = np.stack([w for _, w in trace]).astype(np.float32)
+    with spans.span("repro.sim.trace", epochs=seg, pages=n) as sp:
+        tr, hit = _cached_trace(workload, device=not python_loop)
+        sp.count(cache_hit=int(hit))
+        if not python_loop:
+            reads_t, writes_t = tr.on_device(start, stop)
+    reads_h, writes_h = tr.reads[start:stop], tr.writes[start:stop]
     epoch_ids = np.arange(start, stop, dtype=np.int32)
     const = {k: np.float32(v) for k, v in const.items()}
     scale = workload.scale
@@ -1257,8 +1316,8 @@ def run_epochs(workload, engine_name: str,
             carry = jax.tree_util.tree_map(jnp.asarray, carry)
         outs = []
         for i, e in enumerate(epoch_ids):
-            carry, o = step(carry, (jnp.asarray(reads_t[i]),
-                                    jnp.asarray(writes_t[i]),
+            carry, o = step(carry, (jnp.asarray(reads_h[i]),
+                                    jnp.asarray(writes_h[i]),
                                     jnp.int32(int(e))), kv)
             outs.append(o)
         stacked = tuple(jnp.stack([o[i] for o in outs])
@@ -1290,8 +1349,8 @@ def run_epochs(workload, engine_name: str,
         out = {name: np.asarray(arr) for name, arr in zip(names, stacked)}
         if return_carry:
             out["carry"] = carry_to_host(carry)
-    # hand the materialized trace back so heatmap binning in the caller
-    # does not regenerate it (procedural workloads pay O(n) per epoch)
-    out["trace_reads"] = reads_t
-    out["trace_writes"] = writes_t
+    # hand the cached host trace back (read-only) so heatmap binning and
+    # the drift detector in the caller do not regenerate it
+    out["trace_reads"] = reads_h
+    out["trace_writes"] = writes_h
     return out

@@ -59,6 +59,7 @@ instead — the study finishes slower, never wedges.
 from __future__ import annotations
 
 import collections
+import multiprocessing.connection as mp_connection
 import queue as queue_mod
 import socket
 import threading
@@ -97,6 +98,12 @@ def _result_digest(result: Dict[str, Any]) -> Optional[bytes]:
 class _ProcessFleet:
     """Process-transport fleet: mp workers on this box, queue messaging.
 
+    Each worker reports on a pipe of its own.  A worker that dies while
+    writing (a crash, or an injected kill) then truncates only its own
+    channel, which reads as end-of-file once it is gone; on one shared
+    ``multiprocessing.Queue`` it could die holding the queue's
+    cross-process write lock and mute every other worker for good.
+
     Keeps ``spares`` hot-spare workers booted but never leased: a worker
     death promotes a spare instantly instead of paying a fresh
     interpreter boot on the critical path (under the spawn start method
@@ -113,7 +120,8 @@ class _ProcessFleet:
         use_fork = "fork" in mp.get_all_start_methods() and \
             "jax" not in sys.modules
         self._ctx = mp.get_context("fork" if use_fork else "spawn")
-        self._inbox = self._ctx.Queue()
+        self._outboxes: Dict[int, Any] = {}   # wid -> its pipe's read end
+        self._pending: collections.deque = collections.deque()
         self._heartbeat_s = heartbeat_s
         self._faults = faults
         self._cache_dir = cache_dir
@@ -132,12 +140,15 @@ class _ProcessFleet:
         wid = self._next_wid
         self._next_wid += 1
         q = self._ctx.Queue()
+        reader, writer = self._ctx.Pipe(duplex=False)
         p = self._ctx.Process(
             target=process_main,
-            args=(wid, q, self._inbox, self._heartbeat_s, self._faults,
+            args=(wid, q, writer, self._heartbeat_s, self._faults,
                   self._cache_dir),
             daemon=True, name=f"repro-fleet-w{wid}")
         p.start()
+        writer.close()   # the worker's copy is then the only writer: EOF
+        self._outboxes[wid] = reader
         self._procs[wid] = p
         self._queues[wid] = q
         return wid
@@ -156,12 +167,20 @@ class _ProcessFleet:
         return self._spawn()
 
     def poll(self, timeout: float) -> Optional[Dict[str, Any]]:
-        try:
-            if timeout <= 0:
-                return self._inbox.get_nowait()
-            return self._inbox.get(timeout=timeout)
-        except queue_mod.Empty:
-            return None
+        if not self._pending and self._outboxes:
+            by_conn = {c: w for w, c in self._outboxes.items()}
+            for conn in mp_connection.wait(list(by_conn),
+                                           max(0.0, timeout)):
+                try:
+                    self._pending.append(conn.recv())
+                except (EOFError, OSError):
+                    # the worker is gone, maybe mid-message: stop reading
+                    # it (reap_dead reports the death and its lease)
+                    conn.close()
+                    del self._outboxes[by_conn[conn]]
+        elif not self._pending and timeout > 0:
+            time.sleep(timeout)
+        return self._pending.popleft() if self._pending else None
 
     def send(self, wid: int, msg: Dict[str, Any]) -> None:
         self._queues[wid].put(msg)
@@ -210,12 +229,14 @@ class _ProcessFleet:
                 p.join(timeout=0.5)
                 if p.is_alive():
                     p.kill()
-        for q in list(self._queues.values()) + [self._inbox]:
+        for q in self._queues.values():
             try:
                 q.cancel_join_thread()
                 q.close()
             except Exception:
                 pass
+        for conn in self._outboxes.values():
+            conn.close()
 
 
 class _SocketFleet:

@@ -30,7 +30,8 @@ never the study.
 Transports:
 
 * **process** (:func:`process_main`) — spawned by the coordinator on the
-  same box; messages over ``multiprocessing`` queues.  The worker
+  same box; units arrive on a ``multiprocessing`` queue, and the worker
+  answers on a pipe of its own (see ``_ProcessFleet``).  The worker
   self-terminates when its parent dies, so a SIGKILLed coordinator never
   leaks orphan evaluators.
 * **socket** (:func:`socket_main`, or ``python -m
@@ -274,7 +275,7 @@ def _serve(recv: Callable[[float], Optional[Dict[str, Any]]],
                   "attempt": a})
 
 
-# -- process transport (multiprocessing queues) ------------------------------
+# -- process transport (a queue in, a pipe out) ------------------------------
 def process_main(worker_id: int, inbox, outbox, heartbeat_s: float,
                  faults: FaultPlan, cache_dir: Optional[str]) -> None:
     """Entry point for coordinator-spawned process workers."""
@@ -292,10 +293,10 @@ def process_main(worker_id: int, inbox, outbox, heartbeat_s: float,
         return parent is None or parent.is_alive()
 
     try:
-        _serve(recv, outbox.put, worker_id, heartbeat_s, faults,
+        _serve(recv, outbox.send, worker_id, heartbeat_s, faults,
                parent_alive)
     finally:
-        outbox.cancel_join_thread()
+        outbox.close()
 
 
 # -- socket transport (authenticated frames, reconnect-with-backoff) ---------

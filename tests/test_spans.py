@@ -126,11 +126,13 @@ def test_span_counts(traced):
     for s in got:
         by[s.name].append(s)
     assert by["repro.study.tune"][0].stats == {"budget": 8, "batch_size": 4}
-    for s in by["repro.sim.trace"]:
-        assert s.stats == {"epochs": wl.n_epochs, "pages": wl.n_pages}
-    trace_bytes = 2 * wl.n_epochs * wl.n_pages * 4    # float32 reads, writes
+    # the study's one workload: its trace is built and copied once
+    assert [s.stats for s in by["repro.sim.trace"]] == [
+        {"epochs": wl.n_epochs, "pages": wl.n_pages, "cache_hit": hit}
+        for hit in (0, 1, 1)]
+    # a launch hands over the knob vectors and constants, no (n,) array
     for s in by["repro.sim.launch"]:
-        assert trace_bytes < s.stats["h2d_bytes"] < 2 * trace_bytes
+        assert 0 < s.stats["h2d_bytes"] < 64 * 1024
     assert [s.stats["cache_miss"] for s in by["repro.sim.launch"]] == \
         [1, 1, 0]
     fit, = by["repro.bo.fit"]

@@ -527,3 +527,26 @@ def test_fleet_spec_requires_fleet_executor():
     from repro.core.tune_service import FleetSpec
     with pytest.raises(ValueError, match="fleet_spec"):
         Study(_spec()).tune(budget=2, fleet_spec=FleetSpec.generate())
+
+
+def test_process_fleet_drops_a_truncated_channel():
+    """A process worker killed mid-message leaves a truncated frame on its
+    own pipe: the fleet stops reading that pipe and still hears the rest
+    (on one shared queue such a death could mute every worker)."""
+    import multiprocessing as mp
+    import struct
+
+    from repro.core.tune_service.coordinator import _ProcessFleet
+
+    fleet = _ProcessFleet(0, 0.1, FaultPlan(), None, spares=0)
+    cut_r, cut_w = mp.Pipe(duplex=False)
+    ok_r, ok_w = mp.Pipe(duplex=False)
+    fleet._outboxes.update({0: cut_r, 1: ok_r})
+    os.write(cut_w.fileno(), struct.pack("!i", 100) + b"abc")
+    cut_w.close()
+    ok_w.send({"type": "hello", "worker": 1})
+    got = [fleet.poll(0.5) for _ in range(3)]
+    assert {"type": "hello", "worker": 1} in got
+    assert list(fleet._outboxes) == [1]
+    ok_w.close()
+    fleet.close()
