@@ -15,45 +15,17 @@ for p in (ROOT, os.path.join(ROOT, "src")):
 
 from bench import harness as H  # noqa: E402
 
-
-def with_held_out() -> dict:
-    """BENCHMARK.json with the cells of ``bench/held_out.json`` added: their
-    configurations, and each metric's ``workloads`` widened to them."""
-    bench = H.load_json(ROOT, "BENCHMARK.json")
-    held = H.load_json(H.BENCH, "held_out.json")
-    out = dict(bench, configs=bench["configs"] + held["configs"],
-               workloads=bench["workloads"] + held["workloads"])
-    for key in ("end_to_end", "per_layer"):
-        metrics = {m["name"]: dict(m) for m in bench[key]}
-        for m in held[key]:
-            if m["name"] in metrics:
-                metrics[m["name"]]["workloads"] = \
-                    metrics[m["name"]]["workloads"] + m["workloads"]
-            else:
-                metrics[m["name"]] = dict(m)
-        out[key] = list(metrics.values())
-    return out
+TINY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tiny")
 
 
 def tiny_cell(name: str) -> H.Cell:
-    """The cell ``name`` of BENCHMARK.json or of the held-out cells, cut to
-    a test's size."""
-    cell = H.Cell(with_held_out(), name)
-    if cell.kind == "tune":
-        # scale 0.02 of the GUPS trace: 655 pages, every rate preserved
-        cell.config = dict(cell.config, scale=0.02, n_pages=655)
-        cell.traffic = dict(cell.traffic, budget=8,
-                            batch_size=min(4, cell.traffic["batch_size"]))
-    else:
-        cell.config = dict(
-            cell.config, num_hidden_layers=2, num_key_value_heads=2,
-            head_dim=16, num_attention_heads=8, max_position_embeddings=64,
-            page_tokens=4, batch=4, engine_every=4)
-        roomy = cell.traffic["hbm_pages"] > 128
-        cell.traffic = dict(
-            cell.traffic, hbm_pages=60 if roomy else 12, inputs=7,
-            target_len=dict(median=24, sigma=0.5, lo=8, hi=64, n=16),
-            check_share=0.3, check_pages=8)
+    """The cell ``name`` of BENCHMARK.json, cut to a test's size by
+    ``bench/tests/tiny/<name>.json``: keys that replace those of the
+    configuration (``config``) and of the traffic mix (``traffic``)."""
+    cell = H.Cell(H.load_json(ROOT, "BENCHMARK.json"), name)
+    cut = H.load_json(TINY, name + ".json")
+    cell.config = dict(cell.config, **cut["config"])
+    cell.traffic = dict(cell.traffic, **cut["traffic"])
     return cell
 
 

@@ -20,16 +20,17 @@ from bench import harness as H
 
 TUNE = "tune.gups-hemem.q16"
 SERVE = "serve.cmdrplus-kv.tight"
+CELLS = [w["name"]
+         for w in H.load_json(H.ROOT, "BENCHMARK.json")["workloads"]]
 
 
-@pytest.mark.parametrize("cell", [TUNE, "tune.gups-hemem.q1", SERVE,
-                                  "serve.cmdrplus-kv.roomy"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_sound_run_is_correct(cell):
     out, _, _ = bench_tiny.run_tiny(cell, seed=2 ** 31 + 7)
     assert bench_tiny.correct(out), out["checks"]
 
 
-@pytest.mark.parametrize("cell", [TUNE, SERVE])
+@pytest.mark.parametrize("cell", CELLS)
 def test_control_fails(cell):
     cell_ = bench_tiny.tiny_cell(cell)
     driver = H.load_module(cell_.driver_path, "ctl_" + cell_.kind)
@@ -67,15 +68,26 @@ def _tune_fault(monkeypatch, fault):
         monkeypatch.setattr(Study, "run", broken)
 
 
-@pytest.mark.parametrize("fault", ["unchanged", "half_batch", "altered"])
-def test_tuning_faults_fail(monkeypatch, fault):
+def _run_tune_fault(monkeypatch, cell, fault):
     from repro.core import engine_jax
     _tune_fault(monkeypatch, fault)
     try:
-        out, _, _ = bench_tiny.run_tiny(TUNE)
+        out, _, _ = bench_tiny.run_tiny(cell)
     finally:
         engine_jax._COMPILED.clear()
     assert not bench_tiny.correct(out), out["checks"]
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch", "altered"])
+def test_tuning_faults_fail(monkeypatch, fault):
+    _run_tune_fault(monkeypatch, TUNE, fault)
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "altered"])
+def test_sequential_tuning_faults_fail(monkeypatch, fault):
+    """The q1 cell evaluates one candidate at a time: it has no batch to
+    leave half of out."""
+    _run_tune_fault(monkeypatch, "tune.gups-hemem.q1", fault)
 
 
 # -- serving faults ------------------------------------------------------------

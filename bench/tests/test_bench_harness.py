@@ -14,9 +14,6 @@ BENCH = H.load_json(H.ROOT, "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
-#: BENCHMARK.json with the held-out cells, which must resolve as well
-ALL = bench_tiny.with_held_out()
-ALL_CELLS = [w["name"] for w in ALL["workloads"]]
 
 
 def test_keys():
@@ -25,14 +22,15 @@ def test_keys():
     assert BENCH["paths"] == ["bench"]
     assert os.path.isfile(os.path.join(H.ROOT, BENCH["command"][1]))
     assert 1 <= BENCH["run_seconds"] <= 51
-    # every configuration is some cell's; a held-out cell is not in both
+    # every configuration is some cell's; a pair of configuration and
+    # traffic appears once
     assert {c["name"] for c in BENCH["configs"]} == \
         {w["config"] for w in BENCH["workloads"]}
-    assert len(ALL_CELLS) == len(set(ALL_CELLS))
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
 
 
-@pytest.mark.parametrize("bench", [BENCH, ALL],
-                         ids=["benchmark", "with_held_out"])
+@pytest.mark.parametrize("bench", [BENCH], ids=["benchmark"])
 def test_names_and_units(bench):
     metrics = bench["end_to_end"] + bench["per_layer"]
     names = [m["name"] for m in metrics] + \
@@ -55,12 +53,13 @@ def test_names_and_units(bench):
         assert 0.01 <= m["bound"] <= 0.25
 
 
-@pytest.mark.parametrize("cell", ALL_CELLS)
+@pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves(cell):
-    c = H.Cell(ALL, cell)
+    c = H.Cell(BENCH, cell)
     assert os.path.isfile(c.driver_path)
+    assert os.path.isfile(os.path.join(bench_tiny.TINY, cell + ".json"))
     assert c.config["kind"] == c.traffic["kind"]
-    cfg = next(x for x in ALL["configs"] if x["name"] == c.entry["config"])
+    cfg = next(x for x in BENCH["configs"] if x["name"] == c.entry["config"])
     assert sorted(c.config["reduced"]) == sorted(cfg["reduced"])
     assert c.config["source"] == cfg["source"]
     names = {m["name"] for m in c.end_to_end}
@@ -75,15 +74,14 @@ def test_cell_resolves(cell):
 
 def test_layers_and_kernels_named_once():
     layers = {}
-    for m in ALL["per_layer"]:
+    for m in BENCH["per_layer"]:
         layers.setdefault(m["name"].split(".")[0], set()).add(m["layer"])
     assert all(len(v) == 1 for v in layers.values())
     for k in ("select_topk", "paged_attention", "page_migrate"):
         assert os.path.isfile(os.path.join(H.BENCH, "work", k + ".py"))
 
 
-@pytest.mark.parametrize("cell", ["tune.gups-hemem.q16",
-                                  "serve.cmdrplus-kv.tight"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_driver_builds_the_result_line(cell):
     out, h, _ = bench_tiny.run_tiny(cell)
     assert out["attempted"] > 0 and out["failed"] == 0

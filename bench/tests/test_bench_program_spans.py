@@ -1,5 +1,6 @@
 """The program's own host spans (``repro.*``) beside the benchmark's: what
-the accepted tuning readers read does not depend on them."""
+the device-trace readers read does not depend on them, and the span
+readers read them."""
 
 import pytest
 
@@ -7,8 +8,15 @@ import bench_tiny
 from bench import harness as H
 from bench import trace_reduce as T
 
-READERS = ["bo_host_share.tune", "epoch_loop_ms_per_eval.tune",
-           "select_topk_roofline.tune", "idle_share.tune"]
+READERS = ["epoch_loop_ms_per_eval.tune", "select_topk_roofline.tune",
+           "idle_share.tune"]
+#: each span reader's answer on the hand-made trace (see the idle split in
+#: ``test_program_spans_name_the_idle_gaps``; the trace is in ns)
+SPAN_READERS = {f"{name}.{cell}": v
+                for name, v in [("sim_launch_ms_per_call", 80e-6),
+                                ("bo_fit_ms", 90e-6), ("idle_in_sim", 29.0),
+                                ("idle_in_bo", 21.5)]
+                for cell in ("tune", "seq_tune")}
 
 
 def _trace(program_spans):
@@ -32,17 +40,14 @@ def _trace(program_spans):
             "host": host}
 
 
-def _read(red):
+def _read(red, readers=READERS):
     cell = bench_tiny.tiny_cell("tune.gups-hemem.q16")
-    rec = {"studies": [{"round_times": [
-               {"ask_s": 1.8e-7, "fit_s": 9e-8, "eval_s": 7e-7,
-                "tell_s": 6e-8, "q": 16.0}]}],
-           "evals": [{"value": 1.0}] * 16, "n_epochs": 60, "n_pages": 655}
+    rec = {"evals": [{"value": 1.0}] * 16, "n_epochs": 60, "n_pages": 655}
     ctx = {"peaks": H.peaks_for("TPU v5 lite"), "config": cell.config,
            "traffic": cell.traffic, "work": H.work,
            "roofline_share": H.roofline_share}
     out = {}
-    for name in READERS:
+    for name in readers:
         mod = H.load_module(f"{H.BENCH}/metrics/{name}.py",
                             "bench_metric_" + name.replace(".", "_"))
         out[name] = mod.read(red, rec, ctx)
@@ -58,14 +63,57 @@ def test_accepted_readers_read_the_same_with_program_spans():
     assert all(v is not None for v in _read(bare).values())
 
 
+@pytest.mark.parametrize("name", [r.split(".")[0] for r in READERS])
+def test_sequential_cell_readers_read_as_the_batched(name):
+    # the sequential tuning cell's device-trace readers, on the same trace
+    got = _read(T.reduce(_trace(True)), [f"{name}.tune", f"{name}.seq_tune"])
+    assert got[f"{name}.tune"] is not None
+    assert got[f"{name}.seq_tune"] == got[f"{name}.tune"]
+
+
 def test_program_spans_name_the_idle_gaps():
     bare, spanned = T.reduce(_trace(False)), T.reduce(_trace(True))
-    assert set(bare.idle_by_span) == {"bench.study"}
-    # each gap between the device's work falls, by its midpoint, in the fit,
-    # the trace build or the tell
-    assert spanned.idle_by_span == {
-        "repro.bo.fit": pytest.approx(160e-9),
-        "repro.sim.trace": pytest.approx(295e-9),
-        "repro.bo.tell": pytest.approx(130e-9)}
+    # idle: [0, 160), [185, 480), [870, 1000)
+    assert bare.idle_by_span == {"bench.study": pytest.approx(565e-9),
+                                 T.OUTSIDE: pytest.approx(20e-9)}
+    # each gap split over the self time of the spans it overlaps
+    ns = {T.OUTSIDE: 20, "bench.study": 10, "repro.study.tune": 30,
+          "repro.bo.ask": 35, "repro.bo.fit": 90, "repro.bo.pool": 15,
+          "repro.bo.acquire": 15, "repro.study.eval": 20,
+          "repro.sim.run": 20, "repro.sim.trace": 200,
+          "repro.sim.launch": 60, "repro.sim.fetch": 10,
+          "repro.bo.tell": 60}
+    assert spanned.idle_by_span == {k: pytest.approx(v * 1e-9)
+                                    for k, v in ns.items()}
     assert sum(spanned.idle_by_span.values()) == \
         pytest.approx(sum(bare.idle_by_span.values()))
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_READERS))
+def test_span_reader_reads_the_hand_made_trace(name):
+    got = _read(T.reduce(_trace(True)), [name])[name]
+    assert got == pytest.approx(SPAN_READERS[name])
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_READERS))
+def test_span_reader_finds_nothing_without_program_spans(name):
+    assert _read(T.reduce(_trace(False)), [name])[name] is None
+
+
+def test_program_spans_read_from_a_recorded_trace():
+    """A tiny q16 run traced on this host: the program's spans reach the
+    reduction with their counts, one launch and one fetch per simulation."""
+    import shutil
+    try:
+        _, h, _ = bench_tiny.run_tiny("tune.gups-hemem.q16", trace=True)
+        red = T.reduce(T.load(h.trace_path))
+    finally:
+        shutil.rmtree(H.TRACE_DIR, ignore_errors=True)
+    calls = red.span_calls
+    assert calls["repro.sim.run"] == calls["repro.sim.launch"] == \
+        calls["repro.sim.fetch"] > 0
+    assert red.span_counts["repro.sim.launch"]["h2d_bytes"] > 0
+    assert red.span_counts["repro.sim.trace"]["cache_hit"] == \
+        calls["repro.sim.trace"]
+    assert 0 < red.span_self_s["repro.sim.run"] < red.span_s["repro.sim.run"]
+    assert red.self_ms_per_call("repro.sim.launch") > 0

@@ -1,6 +1,7 @@
 """Reduce a profiler trace (``.xplane.pb``) to the numbers the benchmark
-reads: device busy time, per-kernel and per-program device time, and idle
-gaps attributed to the host span that was open during them.
+reads: device busy time, per-kernel and per-program device time, the
+program's host spans, and idle gaps attributed to the host spans open
+during them.
 
 :func:`load` reads the file into plain event lists; :func:`reduce` does the
 arithmetic on those lists, so it can be checked on events written by hand.
@@ -11,11 +12,15 @@ arithmetic on those lists, so it can be checked on events written by hand.
   (``%select_topk.7 = ...`` -> ``select_topk``); a program by its name
   without the fingerprint (``jit_run(4796...)`` -> ``jit_run``).
 * Host spans: the benchmark's own ``jax.profiler.TraceAnnotation`` events,
-  whose names start with ``bench.``.  ``bench.window`` bounds the window.
+  whose names start with ``bench.``, and the program's ``repro.*`` spans
+  with their numeric stats (counts), taken only from the host thread that
+  holds ``bench.window``: the window's thread drives the timed path, and
+  spans of one thread nest.  ``bench.window`` bounds the window.
 * Busy time is the union of a device's operation intervals inside the
   window, averaged over the devices; the idle share is 1 - busy / window.
-* Each idle interval of a device inside the window is attributed to the
-  innermost ``bench.`` span that contains its midpoint.
+* Each instant of the window belongs to the innermost host span open then
+  (a span's self time: its time less its children's).  A device's idle
+  interval is split over the spans whose self time it overlaps.
 """
 
 from __future__ import annotations
@@ -23,11 +28,15 @@ from __future__ import annotations
 import collections
 import dataclasses
 import re
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
-Event = Tuple[str, float, float]        # (name, start_ns, duration_ns)
+#: (name, start_ns, duration_ns); a host span may carry its counts as a
+#: fourth field, ``{stat: number}``
+Event = Tuple[str, float, float]
 
 WINDOW = "bench.window"
+PROGRAM = "repro."
+OUTSIDE = "outside bench spans"
 _SUFFIX = re.compile(r"\.\d+$")
 
 
@@ -43,12 +52,23 @@ def module_name(name: str) -> str:
 
 
 def load(path: str) -> Dict:
-    """``{"devices": {plane: {"ops": [...], "modules": [...]}},
-    "host": [...]}`` with every event as ``(name, start_ns, dur_ns)``."""
+    """The events of the trace file at ``path`` (see :func:`from_planes`)."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return from_planes(ProfileData.from_file(path).planes)
+
+
+def counts(stats) -> Dict[str, float]:
+    """The numeric stats of an event (bools and strings left out)."""
+    return {k: v for k, v in stats
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def from_planes(planes) -> Dict:
+    """``{"devices": {plane: {"ops": [...], "modules": [...]}},
+    "host": [...]}``: device events as ``(name, start_ns, dur_ns)``, host
+    spans ``bench.*`` likewise and ``repro.*`` with their counts."""
     out = {"devices": {}, "host": []}
-    for plane in pd.planes:
+    for plane in planes:
         if plane.name.startswith("/device:TPU:"):
             dev = {"ops": [], "modules": []}
             for line in plane.lines:
@@ -60,9 +80,14 @@ def load(path: str) -> Dict:
             out["devices"][plane.name] = dev
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
+                events = list(line.events)
                 out["host"] += [(e.name, e.start_ns, e.duration_ns)
-                                for e in line.events
+                                for e in events
                                 if e.name.startswith("bench.")]
+                if any(e.name == WINDOW for e in events):
+                    out["host"] += [(e.name, e.start_ns, e.duration_ns,
+                                     counts(e.stats)) for e in events
+                                    if e.name.startswith(PROGRAM)]
     return out
 
 
@@ -91,6 +116,9 @@ class Reduced:
     module_op_s: Dict[Tuple[str, str], float]
     idle_by_span: Dict[str, float]      # host span -> idle device seconds
     span_s: Dict[str, float]            # host span -> summed seconds
+    span_self_s: Dict[str, float]       # host span -> its self seconds
+    span_calls: Dict[str, int]          # host span -> spans in the window
+    span_counts: Dict[str, Dict[str, float]]   # host span -> summed counts
 
     @property
     def idle_share(self) -> float:
@@ -101,6 +129,21 @@ class Reduced:
             return self.op_s.get(op, 0.0)
         return self.module_op_s.get((module, op), 0.0)
 
+    def idle_under(self, prefix: str) -> Optional[float]:
+        """Idle device seconds in the self time of the spans named
+        ``prefix...``; None where no such span overlaps the window."""
+        if not any(n.startswith(prefix) for n in self.span_calls):
+            return None
+        return sum(s for n, s in self.idle_by_span.items()
+                   if n.startswith(prefix))
+
+    def self_ms_per_call(self, name: str) -> Optional[float]:
+        """Mean self time (ms) of the span ``name`` over its calls that
+        overlap the window, a call cut by the window's edge counting the
+        part inside it; None where none overlaps the window."""
+        n = self.span_calls.get(name, 0)
+        return 1e3 * self.span_self_s.get(name, 0.0) / n if n else None
+
     def breakdown(self) -> Dict[str, List]:
         ops = collections.Counter({f"{m}:{o}": s for (m, o), s
                                    in self.module_op_s.items()})
@@ -110,18 +153,32 @@ class Reduced:
 
 
 def reduce(trace: Dict) -> Reduced:
-    host = trace["host"]
-    wins = [(s, s + d) for n, s, d in host if n == WINDOW]
+    host = [(e[0], e[1], e[2], e[3] if len(e) > 3 else {})
+            for e in trace["host"]]
+    wins = [(s, s + d) for n, s, d, _ in host if n == WINDOW]
     if not wins:
         raise ValueError(f"the trace has no {WINDOW!r} span")
     lo, hi = wins[0]
     window_ns = hi - lo
-    spans = [(n, s, s + d) for n, s, d in host if n != WINDOW]
-    span_s = collections.Counter()
-    for n, a, b in spans:
-        a, b = clip(a, b, lo, hi)
+    spans = []
+    span_s, span_calls = collections.Counter(), collections.Counter()
+    span_counts = collections.defaultdict(collections.Counter)
+    for n, s, d, c in host:
+        if n == WINDOW:
+            continue
+        a, b = clip(s, s + d, lo, hi)
         if b > a:
+            spans.append((n, a, b))
             span_s[n] += (b - a) / 1e9
+        # calls and counts of the same spans whose self time is summed
+        if b > a or lo <= s < hi:
+            span_calls[n] += 1
+            span_counts[n].update(c)
+    owners = self_intervals(spans, lo, hi)
+    span_self_s = collections.Counter()
+    for a, b, n in owners:
+        if n != OUTSIDE:
+            span_self_s[n] += (b - a) / 1e9
 
     op_s, op_calls = collections.Counter(), collections.Counter()
     module_s, module_calls = collections.Counter(), collections.Counter()
@@ -129,6 +186,7 @@ def reduce(trace: Dict) -> Reduced:
     idle = collections.Counter()
     busy_total = 0.0
     devices = trace["devices"]
+    n_dev = max(len(devices), 1)
     for dev in devices.values():
         mods = sorted((s, s + d, module_name(n)) for n, s, d in dev["modules"]
                       if lo <= s < hi)
@@ -149,34 +207,58 @@ def reduce(trace: Dict) -> Reduced:
         busy_total += sum(b - a for a, b in busy)
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         gaps = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
-        for (a, b), name in zip(gaps, spans_at([(a + b) / 2
-                                                for a, b in gaps], spans)):
-            idle[name] += (b - a) / 1e9
-    n_dev = max(len(devices), 1)
+        for name, ns in split(gaps, owners).items():
+            idle[name] += ns / n_dev / 1e9
     return Reduced(window_s=window_ns / 1e9,
                    busy_s=busy_total / n_dev / 1e9,
                    op_s=dict(op_s), op_calls=dict(op_calls),
                    module_s=dict(module_s), module_calls=dict(module_calls),
                    module_op_s=dict(module_op_s),
-                   idle_by_span=dict(idle), span_s=dict(span_s))
+                   idle_by_span=dict(idle), span_s=dict(span_s),
+                   span_self_s=dict(span_self_s),
+                   span_calls=dict(span_calls),
+                   span_counts={n: dict(c) for n, c in span_counts.items()})
 
 
-OUTSIDE = "outside bench spans"
+def self_intervals(spans, lo: float, hi: float):
+    """``[(a, b, name)]``: the window ``[lo, hi)`` cut where the innermost
+    open span changes, each piece named by that span (``OUTSIDE`` where none
+    is open).  ``spans`` are ``(name, start, end)`` inside the window, from
+    nested ``with`` blocks of one thread, so a stack of the open ones is
+    enough; a span that outlives its parent keeps the time it outlives it."""
+    out, stack, cur = [], [], lo
 
+    def emit(t):
+        nonlocal cur
+        if t > cur:
+            out.append((cur, t, stack[-1][0] if stack else OUTSIDE))
+            cur = t
 
-def spans_at(times: List[float], spans) -> List[str]:
-    """The innermost host span open at each of the ascending ``times``.
-    The spans come from nested ``with`` blocks of one thread, so a stack of
-    the open ones is enough."""
-    order = sorted(spans, key=lambda x: (x[1], -x[2]))
-    stack, i, out = [], 0, []
-    for t in times:
-        while i < len(order) and order[i][1] <= t:
-            while stack and stack[-1][2] < order[i][1]:
-                stack.pop()
-            stack.append(order[i])
-            i += 1
-        while stack and stack[-1][2] < t:
+    def close_to(t):
+        while stack and stack[-1][2] <= t:
+            emit(stack[-1][2])
             stack.pop()
-        out.append(stack[-1][0] if stack else OUTSIDE)
+
+    for sp in sorted(spans, key=lambda x: (x[1], -x[2])):
+        close_to(sp[1])
+        emit(sp[1])
+        stack.append(sp)
+    close_to(hi)
+    emit(hi)
+    return out
+
+
+def split(gaps, owners) -> Dict[str, float]:
+    """Each of the ascending disjoint ``gaps`` split over the ``owners``
+    (:func:`self_intervals`) it overlaps: ``{name: overlapped ns}``."""
+    out = collections.Counter()
+    j = 0
+    for a, b in gaps:
+        while j < len(owners) and owners[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(owners) and owners[k][0] < b:
+            x, y, name = owners[k]
+            out[name] += min(b, y) - max(a, x)
+            k += 1
     return out
