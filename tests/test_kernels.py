@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as fa
+from repro.kernels.paged_attention import compact_table, pages_per_block
 from repro.kernels.paged_attention import paged_attention as pa
 from repro.kernels.page_migrate import page_migrate as pm
 
@@ -67,40 +68,103 @@ def test_flash_attention_vs_oracle(B, S, H, KV, D, causal, window, cap,
                                atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("B,H,KV,D,page,ppseq,P", [
-    (2, 8, 4, 64, 16, 4, 16),
-    (3, 4, 1, 128, 8, 8, 64),
-    (1, 16, 8, 64, 32, 2, 8),
+def _paged_table(rng, B, page, ppseq, P, table):
+    """A block table and lengths for one ``table`` kind: ``full`` (every
+    entry a page, random lengths), ``holes`` (-1 at the front and in the
+    middle, full lengths), ``ragged`` (a resident count that is not a
+    multiple of the kernel's pages per block) or ``empty`` (sequence 0 has
+    no resident page, sequence 1 has length 0)."""
+    tbl = np.stack([rng.choice(P, ppseq, replace=False) for _ in range(B)])
+    lengths = np.full(B, page * ppseq)
+    if table == "full":
+        lengths = rng.integers(1, page * ppseq + 1, B)
+    elif table == "holes":
+        tbl[:, 0] = -1
+        tbl[:, ppseq // 2: ppseq // 2 + 2] = -1
+        tbl[rng.random(tbl.shape) < 0.25] = -1
+    elif table == "ragged":
+        n = pages_per_block(page, ppseq) + 3
+        assert n % pages_per_block(page, ppseq) and n < ppseq
+        for row in tbl:
+            row[rng.choice(ppseq, ppseq - n, replace=False)] = -1
+    elif table == "empty":
+        tbl[0] = -1
+        lengths[1] = 0
+    return jnp.asarray(tbl, jnp.int32), jnp.asarray(lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("B,H,KV,D,page,ppseq,P,table", [
+    pytest.param(2, 8, 4, 64, 16, 4, 16, "full", id="2-8-4-64-16-4-16"),
+    pytest.param(3, 4, 1, 128, 8, 8, 64, "full", id="3-4-1-128-8-8-64"),
+    pytest.param(1, 16, 8, 64, 32, 2, 8, "full", id="1-16-8-64-32-2-8"),
+    # Command R+'s grouping: 96 query heads over 8 KV heads of 128 dims
+    pytest.param(2, 96, 8, 128, 16, 20, 24, "full", id="cmdrplus-full"),
+    pytest.param(2, 96, 8, 128, 16, 20, 48, "holes", id="cmdrplus-holes"),
+    pytest.param(2, 96, 8, 128, 16, 20, 48, "ragged", id="cmdrplus-ragged"),
+    pytest.param(3, 8, 2, 64, 16, 24, 64, "holes", id="holes"),
+    pytest.param(2, 8, 2, 32, 5, 40, 64, "ragged", id="ragged-page5"),
+    pytest.param(3, 8, 4, 32, 8, 6, 16, "empty", id="empty"),
 ])
-def test_paged_attention_vs_oracle(B, H, KV, D, page, ppseq, P):
+def test_paged_attention_vs_oracle(B, H, KV, D, page, ppseq, P, table):
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, page, KV, D)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(P, page, KV, D)), jnp.float32)
-    table = jnp.asarray(
-        np.stack([rng.choice(P, ppseq, replace=False) for _ in range(B)]),
-        jnp.int32)
-    lengths = jnp.asarray(rng.integers(1, page * ppseq + 1, B), jnp.int32)
-    out_p = pa(q, kp, vp, table, lengths, interpret=True)
-    out_r = ref.paged_attention_ref(q, kp, vp, table, lengths)
+    tbl, lengths = _paged_table(rng, B, page, ppseq, P, table)
+    out_p = pa(q, kp, vp, tbl, lengths, interpret=True)
+    out_r = ref.paged_attention_ref(q, kp, vp, tbl, lengths)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
+    if table == "empty":
+        assert not np.asarray(out_p[:2]).any()
 
 
 def test_paged_attention_ignores_unused_pages():
-    """Pages past `lengths` must not affect the result."""
+    """Pages past `lengths`, the rows of the last page past it, and the
+    pages behind -1 holes (page 0, where a hole would clamp, and pages no
+    entry names) must not affect the result: poisoning them with NaN
+    leaves the output bit-identical."""
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.normal(size=(1, 4, 32)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(8, 8, 2, 32)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(8, 8, 2, 32)), jnp.float32)
-    table = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-    out_a = pa(q, kp, vp, table, jnp.asarray([9], jnp.int32), interpret=True)
-    kp2 = kp.at[2:].set(999.0)
-    vp2 = vp.at[2:].set(-999.0)
-    out_b = pa(q, kp2, vp2, table, jnp.asarray([9], jnp.int32),
-               interpret=True)
-    np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
-                               atol=1e-6)
+    table = jnp.asarray([[-1, 3, -1, 4, 5, 6]], jnp.int32)
+    lengths = jnp.asarray([35], jnp.int32)   # entry 4 holds 3 tokens
+    out_a = pa(q, kp, vp, table, lengths, interpret=True)
+    kp2, vp2 = kp, vp
+    for rows in (np.s_[0:3], np.s_[6:], np.s_[5, 3:]):
+        kp2 = kp2.at[rows].set(jnp.nan)
+        vp2 = vp2.at[rows].set(jnp.nan)
+    out_b = pa(q, kp2, vp2, table, lengths, interpret=True)
+    assert np.isfinite(np.asarray(out_a)).all()
+    np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
+
+
+@pytest.mark.parametrize("page,ppseq,hole_share", [
+    (16, 128, 0.75), (5, 13, 0.3), (8, 4, 0.0), (32, 9, 1.0)])
+def test_compact_table_is_a_stable_partition(page, ppseq, hole_share):
+    """The compaction before the kernel equals a numpy stable partition of
+    each row into its counted entries (page id >= 0, first token below the
+    length), with each entry's token count."""
+    rng = np.random.default_rng(5)
+    B = 6
+    tbl = rng.integers(0, 1000, (B, ppseq))
+    tbl[rng.random(tbl.shape) < hole_share] = -1
+    lengths = rng.integers(0, page * ppseq + 1, B)
+    lengths[0] = page * ppseq
+    ids, tokens, counts = compact_table(jnp.asarray(tbl, jnp.int32),
+                                        jnp.asarray(lengths, jnp.int32), page)
+    for b in range(B):
+        keep = [j for j in range(ppseq)
+                if tbl[b, j] >= 0 and j * page < lengths[b]]
+        n = len(keep)
+        assert counts[b] == n
+        np.testing.assert_array_equal(ids[b, :n], tbl[b, keep])
+        np.testing.assert_array_equal(
+            tokens[b, :n], np.clip(lengths[b] - np.array(keep, int) * page,
+                                   0, page))
+        assert (np.asarray(ids[b, n:]) == -1).all()
+        assert not np.asarray(tokens[b, n:]).any()
 
 
 @pytest.mark.parametrize("P,elems,n", [(8, 64, 4), (16, 256, 16), (4, 32, 1)])

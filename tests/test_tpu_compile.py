@@ -135,3 +135,28 @@ def test_epoch_loop_compiles_with_pallas_selection(one_chip, monkeypatch):
         lambda a: _shape(one_chip, np.shape(a), a.dtype), args)
     compiled = jax.jit(run).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_serving_decode_compiles(one_chip, monkeypatch):
+    """The fused serving decode step at the Command R+ tight geometry (B=8,
+    128 pages a sequence, 96 HBM pages, 64 layers x 8 KV heads x 128 dims),
+    with paged attention through the Pallas kernel, keeps the kernel under
+    the name the benchmark's trace reader looks up."""
+    from repro.core.serving_jax import CompiledServing
+    from repro.core.tiered_kv import KVSpec
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "FORCE", "pallas")
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    spec = KVSpec(n_layers=64, kv_heads=8, head_dim=128, page_tokens=16,
+                  dtype=jnp.bfloat16)
+    srv = CompiledServing(spec, 8, 128, 96)
+    state = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, a.shape, a.dtype),
+        jax.eval_shape(srv.fresh_state))
+    kv_new = _shape(one_chip, (8, 64, 8, 128), jnp.bfloat16)
+    compiled = srv._decode_fn.lower(
+        state, kv_new, kv_new, _shape(one_chip, (8, 96, 128), jnp.bfloat16),
+        _shape(one_chip, (8,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_attention" in text
