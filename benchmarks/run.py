@@ -28,7 +28,6 @@ MODULES = [
     "fig12_damon_gups",
     "fig13_memtis",
     "bo_overhead",
-    "serving_tiered_kv",
 ]
 
 

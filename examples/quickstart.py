@@ -29,12 +29,11 @@ deprecated pre-PR-2 call pattern.
 The same Study API also drives the REAL serving path (PR 6): engines that
 implement the lifted protocol (``repro.core.engine_jax.register_jax_engine``;
 ``kv-hemem`` ships) compile end-to-end under ``backend="jax"``, and
-``TieredKVCache(compiled=True)`` runs decode as ONE fused jit (append +
-paged attention + read recording) with batched ``page_migrate`` epochs —
-bit-identically to the per-page Python loop.  ``Study.tune(objective=...)``
+``TieredKVCache`` runs decode as ONE fused jit (append + paged attention +
+read recording) with batched ``page_migrate`` epochs, held to the plain
+reference ``bench/reference/serve_ref.py``.  ``Study.tune(objective=...)``
 accepts a custom objective, e.g. a p99-latency/recall score over a
-replayable ``TrafficSpec`` arrival trace; see ``examples/tune_serving.py``
-and ``python -m benchmarks.serving_tiered_kv``.
+replayable ``TrafficSpec`` arrival trace; see ``examples/tune_serving.py``.
 
 **Async tuning & resume** (PR 7): ``--executor async`` hands the study to
 the asynchronous trial-executor service — ``--slots N`` evaluation slots

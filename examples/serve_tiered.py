@@ -2,11 +2,9 @@
 tiering engine migrating KV pages between HBM and host tiers — the paper's
 technique running in the real decode path.
 
-By default this runs the COMPILED serving path: ``decode_step`` is one
-jitted call (append + paged-attention + read-recording fused over the whole
-batch) and engine epochs batch their page moves through ``page_migrate``.
-``--python-loop`` runs the per-page reference loop instead — same residency
-decisions (both modes share one jitted engine executable), ~100x slower.
+``decode_step`` is one jitted call (append + paged-attention +
+read-recording fused over the whole batch) and engine epochs batch their
+page moves through ``page_migrate``.
 
     PYTHONPATH=src python examples/serve_tiered.py [--steps 128] [--tuned]
 """
@@ -28,17 +26,13 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--hbm-pages", type=int, default=24)
     ap.add_argument("--tuned", action="store_true")
-    ap.add_argument("--python-loop", action="store_true",
-                    help="use the per-page reference loop instead of the "
-                         "fused compiled step")
     args = ap.parse_args()
 
     rng = np.random.default_rng(0)
     spec = KVSpec(n_layers=4, kv_heads=2, head_dim=32, page_tokens=8)
     cache = TieredKVCache(spec, batch=args.batch, max_pages_per_seq=64,
                           hbm_pages=args.hbm_pages,
-                          config=TUNED if args.tuned else None,
-                          compiled=not args.python_loop)
+                          config=TUNED if args.tuned else None)
     t0 = time.time()
     for step in range(args.steps):
         k = rng.normal(size=(args.batch, spec.n_layers, spec.kv_heads,
@@ -51,8 +45,7 @@ def main():
             print(f"step {step+1:4d}  recall={cache.recall():.3f}  "
                   f"migrations={cache.migrations:4d}  "
                   f"hbm_util={cache.hbm_utilization():.2f}")
-    mode = "python-loop" if args.python_loop else "compiled"
-    print(f"\n{'tuned' if args.tuned else 'default'} config [{mode}]: "
+    print(f"\n{'tuned' if args.tuned else 'default'} config: "
           f"recall={cache.recall():.3f} migrations={cache.migrations} "
           f"({(time.time()-t0)/args.steps*1e3:.1f} ms/step)")
 

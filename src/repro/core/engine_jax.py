@@ -839,8 +839,9 @@ class KVHeMemDef(_HeMemDef):
     measures per-page attention mass *exactly* (the paged-attention kernel
     computes it), so there is no PEBS interrupt noise to emulate —
     ``sampled = true_counts / sampling_period``.  Determinism is also what
-    lets the compiled serving step be pinned bit-identical to the eager
-    Python ``TieredKVCache`` loop (same jnp ops, jit vs eager).
+    lets the compiled serving step be pinned bit-identical, in residency
+    and migrations, to the plain numpy reference
+    ``bench/reference/serve_ref.py``.
     """
 
     def _draws(self, kv, keys, e, reads, writes):

@@ -1,27 +1,28 @@
 """Compiled tiered-KV serving: the fused decode + engine step.
 
-This module is the jitted backend behind ``TieredKVCache(compiled=True)``.
-One decode step — token append, paged attention over the HBM-resident
-pages, and attention-mass read recording — is a single jitted function over
+This module is the jitted implementation behind ``TieredKVCache``.  One
+decode step — token append, paged attention over the HBM-resident pages,
+and attention-mass read recording — is a single jitted function over
 ``(B, pages)`` arrays; engine epochs run as two more jitted calls (decide +
 apply) with page moves batched through ONE :func:`~repro.kernels.ops.
-page_migrate` call per direction instead of the per-page Python loops of
-the reference path.
+page_migrate` call per direction.
 
-Conformance is **by construction**, not by tolerance:
+Conformance is checked against the plain reference
+``bench/reference/serve_ref.py``, which replays the same steps in numpy:
 
 * The engine's observe/plan math (:class:`~repro.core.engine_jax.
   KVHeMemDef`, the first lifted engine) is compiled ONCE per cache
-  geometry, and the *same jitted executable* serves both the compiled path
-  and the Python reference loop in :mod:`~repro.core.tiered_kv`.  XLA is
-  free to fuse differently across different jit programs (observed ~1-ULP
-  drift in the cooling EWMAs between eager and jitted traces), so sharing
-  the executable is the only way residency decisions stay bit-identical.
+  geometry (:attr:`CompiledServing.engine_decide`), so every cache of that
+  geometry runs the same executable.  The reference's float32 math is not
+  the executable's: XLA may order a reduction or fuse an EWMA differently
+  (about 1 ULP of drift in the cooling state has been seen), so the
+  bitwise residency contract holds only while no sum lands within a few
+  ULPs of a threshold it is compared to (a cooling trigger, a hot
+  threshold).
 * Access accounting is *integer*: one decode step charges each logical
   page ``step_read_counts`` accesses — pure int32 arithmetic, so numpy,
   eager jnp and any jit fusion produce the same bits, and the int->f32
-  conversion fed to the engine is the same correctly-rounded value on both
-  paths.
+  conversion fed to the engine is a correctly-rounded value.
 
 Structural state (``slot_of``, ``page_of_slot``, ``lengths``) is integer
 throughout; both page pools carry one extra **dump row** (index ``H`` for
@@ -42,8 +43,7 @@ from repro.kernels import ops as kops
 
 from . import engine_jax
 from .engine_jax import KVHeMemDef
-from .traffic import step_read_counts  # noqa: F401  (re-export; shared
-#                                        with the Python reference loop)
+from .traffic import step_read_counts
 
 # this module is jax-only; bind engine_jax's lazy jax globals up front so
 # the engine defs are usable without a prior simulator call
@@ -61,9 +61,8 @@ class CompiledServing:
 
     All methods are pure: state pytree in, state pytree out.  Instances are
     cached per ``(spec, batch, max_pages, hbm_pages, kernel path)`` by
-    :func:`get_serving` so every ``TieredKVCache`` of the same geometry —
-    including the Python-loop reference, which borrows :attr:`engine_decide`
-    — shares one set of compiled executables.
+    :func:`get_serving` so every ``TieredKVCache`` of the same geometry
+    shares one set of compiled executables.
     """
 
     def __init__(self, spec, batch: int, max_pages: int, hbm_pages: int):
@@ -88,7 +87,7 @@ class CompiledServing:
         self._decode_fn = jax.jit(self._decode, donate_argnums=0)
         self._apply_fn = jax.jit(self._apply, donate_argnums=0)
         self._reset_fn = jax.jit(self._reset, donate_argnums=0)
-        # the ONE engine-decision executable both paths share (see module
+        # the ONE engine-decision executable of this geometry (see module
         # docstring); knob vectors are traced, so tuner configs never retrace
         self.engine_decide = jax.jit(self._engine_decide)
 
@@ -129,8 +128,8 @@ class CompiledServing:
         writes = st["writes"].at[pid].add(active.astype(jnp.int32))
         slot = st["slot_of"][pid]
         # first touch of a page grabs the lowest free HBM slot; the j-th
-        # needy sequence (ascending b) gets the j-th lowest free slot —
-        # exactly the reference loop's repeated flatnonzero(free)[0]
+        # needy sequence (ascending b) gets the j-th lowest free slot, the
+        # plain reference's lowest-free-slot rule
         need = active & (slot < 0) & (off == 0)
         free = st["page_of_slot"][:H] < 0
         n_free = free.sum()
@@ -198,8 +197,8 @@ class CompiledServing:
     def _apply(self, st, pmask, dmask):
         """Apply one epoch's migration masks: batched demote (HBM->host),
         then batched promote into the freed slots — promote page-ids
-        ascending paired with free slots ascending, the reference loop's
-        repeated lowest-free-slot rule."""
+        ascending paired with free slots ascending, the plain reference's
+        lowest-free-slot rule."""
         n, H = self.n, self.H
         arn = jnp.arange(n, dtype=jnp.int32)
         slots = st["slot_of"][:n]
@@ -237,8 +236,8 @@ class CompiledServing:
                     epoch=st["epoch"] + 1), moved
 
     def engine_step(self, st, kv, dt_ms):
-        """One engine epoch on compiled state: shared decide + batched
-        apply.  Returns ``(state, moved)``."""
+        """One engine epoch on compiled state: decide + batched apply.
+        Returns ``(state, moved)``."""
         in_fast = st["slot_of"][:self.n] >= 0
         eng, pmask, dmask = self.engine_decide(
             st["eng"], kv, st["reads"].astype(jnp.float32),

@@ -38,17 +38,17 @@ def step_read_counts(lengths, max_pages: int, page_tokens: int, scale: int,
                      xp=np):
     """Integer per-page access counts for one decode step.
 
-    The serving access pattern (attention sink + recency + uniform base —
-    the float profile of ``TieredKVCache.true_attention_mass``) quantized
-    to int32 access counts: for a sequence covering ``n_p`` pages,
+    The serving access pattern (attention sink + recency + uniform base)
+    quantized to int32 access counts: for a sequence covering ``n_p``
+    pages,
 
     * every active page gets ``scale // (20 * n_p)``        (~0.05 mass),
     * page 0 additionally ``35 * scale // 100``             (~0.35, sink),
     * the last ``min(n_p, 2)`` pages additionally
       ``45 * scale // (100 * min(n_p, 2))``                 (~0.45, recency).
 
-    Pure integer arithmetic, so ``xp=np`` (reference loop, this module's
-    workload replay) and ``xp=jnp`` (inside the fused serving jit) agree
+    Pure integer arithmetic, so ``xp=np`` (this module's workload replay)
+    and ``xp=jnp`` (inside the fused serving jit) agree
     bitwise — the engine-input exactness the serving conformance tests
     rely on.  Returns ``(counts, active_page)``: ``(B, max_pages)`` int32
     counts and the boolean active-page mask.

@@ -4,6 +4,8 @@ import sys
 # tests must see ONE device (the dry-run sets 512 itself, in its own process)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the checkout itself, for the plain references under bench/reference
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 # CI conformance matrix: REPRO_KERNELS_FORCE=pallas|ref pins the kernels
 # dispatch (repro.kernels.ops.FORCE) for the whole session, so both paths

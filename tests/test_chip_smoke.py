@@ -74,17 +74,12 @@ def test_serving_phase_passes_on_cpu(smoke, force):
 
 
 def test_serving_phase_catches_divergence(smoke, force, monkeypatch):
-    """A reference loop that never migrates fails the phase."""
+    """A reference that never migrates fails the phase."""
     import jax.numpy as jnp
-    from repro.core.tiered_kv import KVSpec, TieredKVCache
+    from bench.reference.serve_ref import TieredKVReference
+    from repro.core.tiered_kv import KVSpec
     force("ref")
-    real = TieredKVCache.step_engine
-
-    def compiled_only(self, dt_ms):
-        if self.compiled:
-            real(self, dt_ms)
-
-    monkeypatch.setattr(TieredKVCache, "step_engine", compiled_only)
+    monkeypatch.setattr(TieredKVReference, "engine", lambda self, dt_ms: 0)
     spec = KVSpec(n_layers=1, kv_heads=2, head_dim=128, page_tokens=16,
                   dtype=jnp.bfloat16)
     with pytest.raises(smoke.SmokeFailure, match="compiled == reference"):
@@ -124,13 +119,20 @@ def test_last_line_is_the_device_json(smoke, capsys, monkeypatch):
     assert ran == ["t", "s"]
 
 
-def test_reference_batch_cut_fits_host(smoke, monkeypatch):
-    from repro.core.tiered_kv import KVSpec
-    spec = KVSpec(n_layers=42, kv_heads=8, head_dim=256, page_tokens=16)
-    page = 42 * 16 * 8 * 256 * 4
-    free = {"SC_AVPHYS_PAGES": 4 * 2 * 64 * page // 4096,
-            "SC_PAGE_SIZE": 4096}
-    monkeypatch.setattr(os, "sysconf", lambda k: free[k])
-    assert smoke._reference_batch(8, 64, spec) == 2
-    free["SC_AVPHYS_PAGES"] *= 100
-    assert smoke._reference_batch(8, 64, spec) == 8
+def test_serving_phase_catches_nan_output(smoke, force, monkeypatch):
+    """A decode step whose attention output is all NaN fails the phase:
+    the attention check must not read a NaN error as a small one."""
+    import jax.numpy as jnp
+    from repro.core.tiered_kv import KVSpec, TieredKVCache
+    force("ref")
+    real = TieredKVCache.decode_step
+
+    def nan_out(self, *a, **kw):
+        return jnp.full_like(real(self, *a, **kw), jnp.nan)
+
+    monkeypatch.setattr(TieredKVCache, "decode_step", nan_out)
+    spec = KVSpec(n_layers=1, kv_heads=2, head_dim=128, page_tokens=16,
+                  dtype=jnp.bfloat16)
+    with pytest.raises(smoke.SmokeFailure, match="decode attention"):
+        smoke.serving_phase(spec=spec, n_heads=2, max_pages=8, hbm_pages=24,
+                            steps=80, engine_every=8)

@@ -1,35 +1,37 @@
-"""Compiled tiered-KV serving: conformance, traffic, deprecations, cache.
+"""Compiled tiered-KV serving: conformance, traffic, dispatch, cache.
 
 The contract under test (see ``repro.core.serving_jax``):
 
-* the fused compiled decode/engine step is conformant **by construction**
-  with the per-page Python reference loop — identical HBM residency sets
-  and migration counts, because both modes share ONE jitted engine-decision
-  executable and feed it bit-identical integer access counts;
+* the fused compiled decode/engine step agrees with the plain reference
+  ``bench/reference/serve_ref.py`` — identical HBM residency, lengths and
+  migration counts at every engine epoch, and attention outputs within the
+  serving cell's ``attn_gap`` limit;
 * traffic replay (``repro.core.traffic``) is deterministic in
   ``(spec, seed)`` and JSON-round-trippable;
 * the lifted ``kv-hemem`` engine dispatches through the compiled jax
   backend (no numpy-fallback warning);
-* ``record_reads`` is the public name (``_record_reads`` is a deprecated
-  shim) and is fused — hence unavailable — on the compiled path.
+* ``TieredKVCache`` has one implementation: ``compiled=False`` raises.
 """
 
 import logging
-import warnings
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
+from bench.reference.serve_ref import TieredKVReference, read_counts
 from repro.core import ExperimentSpec, SimOptions, Study
 from repro.core.workloads import make_workload
-from repro.core.knobs import HEMEM_SPACE
 from repro.core.tiered_kv import KVSpec, TieredKVCache
 from repro.core.traffic import (TrafficSpec, arrival_trace, replay_schedule,
                                 step_read_counts)
 
 SPEC = KVSpec(n_layers=1, kv_heads=2, head_dim=8, page_tokens=8)
+
+#: the serving cell's limit on attention outputs, relative to the
+#: reference's largest magnitude (bench/configs/cmdrplus-kv.json)
+ATTN_GAP = 0.02
 
 #: corner configs for the conformance sweep: default; hair-trigger
 #: promotion with fast epochs; sluggish cooling with slow epochs; tiny
@@ -44,55 +46,108 @@ CORNERS = [
 ]
 
 
-def _drive(cache: TieredKVCache, steps: int, *, engine_every=5, seed=0):
+def _reference(cache: TieredKVCache) -> TieredKVReference:
+    """The plain reference of ``cache``'s geometry and knobs, with the page
+    bytes the compiled engine plans with (2 bytes an element)."""
+    s = cache.spec
+    page_elems = s.n_layers * s.page_tokens * s.kv_heads * s.head_dim
+    return TieredKVReference(cache.batch, cache.max_pages, s.page_tokens,
+                             cache.hbm_pages, s.n_layers, s.kv_heads,
+                             cache.config, page_elems * 2)
+
+
+def _stored(x) -> np.ndarray:
+    """``x`` as the cache stores it (rounded to ``SPEC.dtype``), in
+    float32, so both sides attend the same values."""
+    return np.asarray(jax.numpy.asarray(x, SPEC.dtype), np.float32)
+
+
+def _drive(cache: TieredKVCache, ref: TieredKVReference, steps: int, *,
+           engine_every=5, seed=0):
     """Deterministic decode loop with completion-style resets (a sequence
-    finishes after 16 + 3*b tokens — staggered per slot); returns per-epoch
-    (slot_of, migrations) snapshots."""
-    B = cache.batch
+    finishes after 16 + 3*b tokens — staggered per slot), run on the cache
+    and replayed on the reference.  Every step draws its own K, V and q, so
+    an output depends on which tokens are attended.  Returns per-epoch
+    snapshots of both, ``(slot_of, lengths, migrations)``, every step's
+    attention error relative to the reference's largest magnitude, and the
+    reference's recall."""
+    B, s = cache.batch, cache.spec
     rng = np.random.default_rng(seed)
-    k = rng.normal(size=(B, cache.spec.n_layers, cache.spec.kv_heads,
-                         cache.spec.head_dim)).astype(np.float32)
-    q = rng.normal(size=(B, cache.spec.kv_heads,
-                         cache.spec.head_dim)).astype(np.float32)
+    # layer-0 K/V of every step input, by writer (step * B + sequence)
+    k0 = np.empty((steps * B, s.kv_heads, s.head_dim), np.float32)
+    v0 = np.empty_like(k0)
     limit = 16 + 3 * np.arange(B)
-    snaps = []
+    snaps, errs, num, den = [], [], 0, 0
     for t in range(steps):
-        cache.decode_step(k, k, q)
+        k = _stored(rng.normal(size=(B, s.n_layers, s.kv_heads, s.head_dim)))
+        v = _stored(rng.normal(size=(B, s.n_layers, s.kv_heads, s.head_dim)))
+        q = _stored(rng.normal(size=(B, s.kv_heads, s.head_dim)))
+        k0[t * B:(t + 1) * B], v0[t * B:(t + 1) * B] = k[:, 0], v[:, 0]
+        out = np.asarray(cache.decode_step(k, v, q), np.float32)
+        ref.append(t)
+        ref.record()
+        counts = read_counts(ref.lengths, ref.mp, ref.pt, ref.scale)
+        num += int(counts.reshape(-1)[ref.slot_of >= 0].sum())
+        den += int(counts.sum())
+        want = ref.attend(q, k0, v0)
+        errs.append(float(np.abs(out - want).max() / np.abs(want).max()))
         if t % engine_every == engine_every - 1:
             cache.step_engine(50.0)
-            snaps.append((cache.slot_of.copy(), cache.migrations))
+            ref.engine(50.0)
+            snaps.append(((cache.slot_of, cache.lengths, cache.migrations),
+                          (ref.slot_of.copy(), ref.lengths.copy(),
+                           ref.migrations)))
         done = cache.lengths >= limit
         if done.any():
             cache.reset_seqs(done)
-    return snaps
+            ref.reset(done)
+    return snaps, np.array(errs), num / max(den, 1)
 
 
 @pytest.mark.parametrize("config", CORNERS)
 def test_compiled_matches_reference_residency(config):
-    """Compiled and reference modes agree on HBM residency (which logical
-    pages sit in fast memory) and on total migration counts at every
-    engine epoch — bitwise, not approximately."""
-    kw = dict(batch=3, max_pages_per_seq=4, hbm_pages=5, config=config)
-    ref = TieredKVCache(SPEC, compiled=False, **kw)
-    com = TieredKVCache(SPEC, compiled=True, **kw)
-    sr = _drive(ref, 60)
-    sc = _drive(com, 60)
-    assert len(sr) == len(sc) == 12
-    for e, ((slot_r, mig_r), (slot_c, mig_c)) in enumerate(zip(sr, sc)):
-        assert mig_r == mig_c, f"epoch {e}: migration counts diverge"
+    """The cache and the plain reference agree on HBM residency (which
+    logical pages sit in fast memory), lengths and total migration counts
+    at every engine epoch — bitwise, not approximately — and on every
+    attention output within the serving cell's limit.  The engine's float32
+    math (jitted) and the reference's (numpy) may differ by an ULP in the
+    cooling state; bitwise residency holds because at these corners no sum
+    lands within an ULP of a cooling trigger or hot threshold (see
+    ``repro.core.serving_jax``)."""
+    cache = TieredKVCache(SPEC, batch=3, max_pages_per_seq=4, hbm_pages=5,
+                          config=config)
+    snaps, errs, recall = _drive(cache, _reference(cache), 60)
+    assert len(snaps) == 12
+    for e, ((slot_c, len_c, mig_c), (slot_r, len_r, mig_r)) in \
+            enumerate(snaps):
+        assert mig_c == mig_r, f"epoch {e}: migration counts diverge"
         np.testing.assert_array_equal(
-            slot_r >= 0, slot_c >= 0,
-            err_msg=f"epoch {e}: HBM residency sets diverge")
-    assert ref.recall() == pytest.approx(com.recall(), abs=1e-9)
+            slot_c, slot_r, err_msg=f"epoch {e}: HBM slots diverge")
+        np.testing.assert_array_equal(
+            len_c, len_r, err_msg=f"epoch {e}: lengths diverge")
+    # a NaN error must fail: NaN <= x is False, and max() would drop it
+    assert np.isfinite(errs).all(), "attention outputs not finite"
+    assert errs.max() <= ATTN_GAP, \
+        f"attention gap {errs.max():.3e} > {ATTN_GAP}"
+    assert cache.recall() == pytest.approx(recall, abs=1e-9)
     if config is None or config.get("migration_period", 10) <= 10:
-        assert sr[-1][1] > 0, "sweep produced no migrations (test too weak)"
+        assert snaps[-1][0][2] > 0, \
+            "sweep produced no migrations (test too weak)"
+
+
+def test_compiled_false_raises():
+    """There is one implementation; the plain reference lives under
+    bench/reference."""
+    with pytest.raises(ValueError, match="one implementation"):
+        TieredKVCache(SPEC, batch=2, max_pages_per_seq=3, hbm_pages=4,
+                      compiled=False)
 
 
 def test_compiled_decode_equals_append_attend():
     """decode_step is the fusion of append + attend (same state, output)."""
     kw = dict(batch=2, max_pages_per_seq=3, hbm_pages=4)
-    a = TieredKVCache(SPEC, compiled=True, **kw)
-    b = TieredKVCache(SPEC, compiled=True, **kw)
+    a = TieredKVCache(SPEC, **kw)
+    b = TieredKVCache(SPEC, **kw)
     rng = np.random.default_rng(1)
     k = rng.normal(size=(2, 1, 2, 8)).astype(np.float32)
     q = rng.normal(size=(2, 2, 8)).astype(np.float32)
@@ -109,8 +164,8 @@ def test_compiled_decode_equals_append_attend():
 
 
 def test_step_read_counts_numpy_jax_bitwise():
-    """The integer access profile both paths feed the engine is identical
-    under numpy and jitted jax (pure int32 arithmetic, the conformance
+    """The integer access profile fed to the engine is identical under
+    numpy and jitted jax (pure int32 arithmetic, the conformance
     anchor)."""
     import jax.numpy as jnp
     lengths = np.array([0, 1, 7, 8, 9, 64], np.int32)
@@ -170,27 +225,6 @@ def test_kv_hemem_jax_dispatch_no_fallback(caplog):
             options=SimOptions(backend="jax"))).run()
     assert res.total_s > 0
     assert not any("falling back" in r.getMessage() for r in caplog.records)
-
-
-# -- deprecations / API edges ----------------------------------------------
-
-def test_record_reads_public_and_shim():
-    cache = TieredKVCache(SPEC, batch=2, max_pages_per_seq=3, hbm_pages=4)
-    rng = np.random.default_rng(0)
-    k = rng.normal(size=(2, 1, 2, 8))
-    cache.append(k, k)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # public name: no warning
-        cache.record_reads()
-    with pytest.deprecated_call():
-        cache._record_reads()
-
-
-def test_compiled_record_reads_is_fused():
-    cache = TieredKVCache(SPEC, batch=2, max_pages_per_seq=3, hbm_pages=4,
-                          compiled=True)
-    with pytest.raises(RuntimeError, match="fuses read recording"):
-        cache.record_reads()
 
 
 # -- XLA compile cache plumbing --------------------------------------------

@@ -37,13 +37,14 @@ def test_tiered_kv_engine_keeps_hot_pages_resident():
     rng = np.random.default_rng(1)
     spec = KVSpec(n_layers=1, kv_heads=1, head_dim=8, page_tokens=4)
 
+    q = rng.normal(size=(1, 1, 8))
+
     def run(config, migrate: bool):
         cache = TieredKVCache(spec, batch=1, max_pages_per_seq=64,
                               hbm_pages=8, config=config)
         for step in range(180):
             k = rng.normal(size=(1, 1, 1, 8))
-            cache.append(k, k)
-            cache.record_reads()
+            cache.decode_step(k, k, q)
             if migrate and step % 10 == 9:
                 cache.step_engine(100.0)
         return cache
